@@ -61,4 +61,6 @@ def run(emit) -> None:
 
 if __name__ == "__main__":
     from benchmarks.common import CsvEmitter
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(CsvEmitter())

@@ -49,6 +49,7 @@ from benchmarks.common import (SERVING_BENCH_SCHEMA_VERSION, bench_cfg,
                                full_cfg, get_mixed_dataset)
 from repro.core import predictor
 from repro.core.engine_config import EngineConfig, ObservabilityConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import PredictorEngine, Request
 from repro.serving.service import (TIER_TRANSITIONS_TOTAL, ServiceSLA,
                                    SimulationService)
@@ -350,6 +351,7 @@ def main() -> None:
                     help="enable span tracing; dump the last level's "
                          "Chrome/Perfetto trace JSON here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     quick = args.quick
     levels = args.tenants or ([1, 8] if quick else [1, 8, 64])

@@ -45,6 +45,7 @@ from repro.core.engine_config import EngineConfig, SamplingConfig
 from repro.core.simulate import capsim_simulate
 from repro.core.standardize import build_vocab
 from repro.isa import funcsim, multicore, progen, timing
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import REGISTRY
 
 BENCHES = ["503.bwaves", "505.mcf", "548.exchange2"]
@@ -1225,6 +1226,7 @@ if __name__ == "__main__":
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{args.mesh}").strip()
+    enable_compile_cache()
     emitter = CsvEmitter()
     engine_config = resolve_engine_config(args.engine_config, args.quick)
     if args.obs_overhead:

@@ -12,6 +12,7 @@ import time
 import traceback
 
 from benchmarks.common import CsvEmitter
+from repro.launch.compile_cache import enable_compile_cache
 
 SECTIONS = [
     ("sampler", "bench_sampler", "Fig 3/8: clip distribution + sampler"),
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated section names")
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     emit = CsvEmitter()

@@ -118,15 +118,18 @@ def save(state, step: int, ckpt_dir: str, *, host: int = 0,
         # writers racing the same step can interleave rmtree/rename, so
         # retry through the window — last writer wins, and a loser never
         # leaves a half-deleted final dir (rmtree happens on OUR tmp's
-        # turn only; the published dir is always a complete rename).
-        for attempt in range(5):
+        # turn only; the published dir is always a complete rename).  A
+        # writer can lose the window once per rival, more often on a
+        # loaded host, so the bound is far above any writer count.
+        attempts = 64
+        for attempt in range(attempts):
             if final.exists():
                 shutil.rmtree(final, ignore_errors=True)
             try:
                 tmp.rename(final)                        # atomic publish
                 break
             except OSError:
-                if attempt == 4:
+                if attempt == attempts - 1:
                     raise
     except BaseException:
         shutil.rmtree(tmp, ignore_errors=True)

@@ -134,6 +134,21 @@ def predict_fn(cfg, use_context: bool = True):
 
 
 @lru_cache(maxsize=64)
+def reference_fn(cfg, use_context: bool = True):
+    """Cached jit'd accuracy reference: the monolithic ``forward`` in
+    float32, XLA attention, ``highest`` matmul precision.  On the TPU an
+    f32 matmul otherwise runs at reduced precision, so every serving
+    tier is held to this step rather than to another tier; on the CPU it
+    computes what the monolithic fp32 step computes."""
+    ref = cfg.replace(dtype="float32", attn_impl="chunked")
+
+    def step(p, b):
+        with jax.default_matmul_precision("highest"):
+            return pred_mod.predict_step(p, b, ref, use_context)
+    return jax.jit(step)
+
+
+@lru_cache(maxsize=64)
 def predict_cached_fn(cfg, use_context: bool = True):
     """Cached jit'd RT-cache predict step: the batch carries ``rt_idx``
     rows into a device-resident RT table, so only the block encoder +
@@ -843,6 +858,11 @@ class SimulationEngine:
         """Canonical constructor: every public entry point routes here."""
         return cls(params, cfg, vocab, config,
                    timing_params=timing_params)
+
+    @property
+    def rt_cache(self) -> Optional[RTCache]:
+        """The engine's RT cache (None on the monolithic path)."""
+        return self._rt_cache
 
     def submit(self, bench: progen.Benchmark) -> None:
         self._queue.append(bench)

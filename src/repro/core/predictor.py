@@ -38,6 +38,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import shard_logical
 from repro.models.layers import (
@@ -454,63 +455,51 @@ def forward_cached_fused(params, plan, batch, cfg):
 # only cross-device traffic is the batch scatter / result gather.
 
 def _batch_shard_specs(mesh, token_key: str):
-    from jax.sharding import PartitionSpec as P
     data = P(mesh.axis_names[0])
     return {token_key: data, "context_tokens": data, "clip_mask": data}
+
+
+def _shard_batch(f, mesh, in_specs):
+    """``f`` shard_mapped over the batch axis of ``mesh``: ``in_specs``
+    place the inputs, every output row stays on its shard."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(mesh.axis_names[0]), check_vma=False)
 
 
 def sharded_predict_step(cfg, use_context: bool, mesh):
     """``predict_step`` shard_mapped over the batch axis of ``mesh``
     (monolithic path: batch carries clip_tokens)."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.distributed.sharding import compat_shard_map
-    return compat_shard_map(
-        lambda p, b: predict_step(p, b, cfg, use_context),
-        mesh=mesh,
-        in_specs=(P(), _batch_shard_specs(mesh, "clip_tokens")),
-        out_specs=P(mesh.axis_names[0]))
+    return _shard_batch(
+        lambda p, b: predict_step(p, b, cfg, use_context), mesh,
+        (P(), _batch_shard_specs(mesh, "clip_tokens")))
 
 
 def sharded_forward_cached(cfg, use_context: bool, mesh):
     """``forward_cached`` shard_mapped over the batch axis of ``mesh``;
     the RT table replicates so every shard gathers locally."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.distributed.sharding import compat_shard_map
-    return compat_shard_map(
+    return _shard_batch(
         lambda p, table, b: forward_cached(p, table, b, cfg, use_context),
-        mesh=mesh,
-        in_specs=(P(), P(), _batch_shard_specs(mesh, "rt_idx")),
-        out_specs=P(mesh.axis_names[0]))
+        mesh, (P(), P(), _batch_shard_specs(mesh, "rt_idx")))
 
 
 def sharded_forward_cached_fused(cfg, mesh):
     """``forward_cached_fused`` shard_mapped over the batch axis; params,
     RT table and serving plan replicate."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.distributed.sharding import compat_shard_map
     data = P(mesh.axis_names[0])
     specs = {"rt_idx": data, "ctx_uniq": data, "ctx_count": data,
              "clip_mask": data}
-    return compat_shard_map(
-        lambda p, plan, b: forward_cached_fused(p, plan, b, cfg),
-        mesh=mesh, in_specs=(P(), P(), specs),
-        out_specs=P(mesh.axis_names[0]))
+    return _shard_batch(
+        lambda p, plan, b: forward_cached_fused(p, plan, b, cfg), mesh,
+        (P(), P(), specs))
 
 
 def sharded_encode_instructions(cfg, mesh):
     """``encode_instructions`` shard_mapped over the static-row axis:
     the RT-cache *build* divides by mesh size while the resulting table
     stays byte-identical (rows encode independently)."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.distributed.sharding import compat_shard_map
-    data = P(mesh.axis_names[0])
-    return compat_shard_map(
-        lambda p, rows: encode_instructions(p, rows, cfg),
-        mesh=mesh, in_specs=(P(), data), out_specs=data)
+    return _shard_batch(
+        lambda p, rows: encode_instructions(p, rows, cfg), mesh,
+        (P(), P(mesh.axis_names[0])))
 
 
 # Inference precision knob: fp32 is the bitwise-reference mode; bf16 keeps

@@ -270,19 +270,6 @@ def axis_rules(logical_axes: Sequence[Optional[str]],
     return P(*spec)
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs,
-                     check_vma: bool = False):
-    """``jax.shard_map`` across jax versions: top-level with ``check_vma``
-    on >= 0.6, ``jax.experimental.shard_map`` with the older ``check_rep``
-    spelling before that."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def logical_sharding(logical_axes: Sequence[Optional[str]],
                      mesh: Optional[Mesh] = None,
                      rules: Optional[Rules] = None) -> NamedSharding:

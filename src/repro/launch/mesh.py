@@ -3,18 +3,7 @@ device state; jax locks the device count on first backend init)."""
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with explicit-Auto axis types where the installed
-    jax supports them (>= 0.6, where meshes default to explicit sharding
-    contexts) and plain construction on older releases that predate
-    ``jax.sharding.AxisType``."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,12 +11,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2,16,16)=512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh():
     """1-device mesh with the standard axis names (CPU tests)."""
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_data_mesh(n_shards: int):
@@ -39,14 +29,17 @@ def make_data_mesh(n_shards: int):
     jax's first backend init)."""
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")
-    have = len(jax.devices())
-    if n_shards > have:
+    devices = jax.devices()
+    if n_shards > len(devices):
+        kinds = sorted({f"{d.platform}:{d.device_kind}" for d in devices})
         raise ValueError(
-            f"mesh of {n_shards} devices requested but only {have} "
-            f"visible — on CPU, set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={n_shards} before "
-            "jax initializes its backend")
-    return make_mesh_compat((n_shards,), ("data",))
+            f"mesh of {n_shards} devices requested but JAX found "
+            f"{len(devices)} ({', '.join(kinds)}) — run on a host with "
+            f"{n_shards} accelerators, or for host-CPU devices set "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={n_shards} "
+            "before jax initializes its backend")
+    return jax.make_mesh((n_shards,), ("data",),
+                         axis_types=(AxisType.Auto,))
 
 
 def mesh_axis_sizes(mesh) -> dict:

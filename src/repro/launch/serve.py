@@ -159,6 +159,30 @@ def serve_capsim(args) -> None:
         metrics_server.shutdown()
 
 
+def service_requests(config, vocab, n_benchmarks: int, n_requests: int):
+    """The service's traffic: one checkpoint of each of the first
+    ``n_benchmarks`` Table II programs, tokenized by ``build_dataset``
+    and split into ``n_requests`` equal ``Request``s."""
+    from repro.data.dataset import BuildConfig, build_dataset
+    from repro.isa import progen
+    from repro.serving.engine import Request
+
+    names = list(progen.TABLE_II)[:n_benchmarks]
+    bcfg = BuildConfig(interval_size=config.interval_size, warmup=0,
+                       max_checkpoints=1, l_min=100,
+                       l_clip=config.l_clip, l_token=config.l_token)
+    ds = build_dataset(names, bcfg, vocab)
+    per_req = max(1, len(ds) // max(n_requests, 1))
+    requests = []
+    for i in range(n_requests):
+        lo = (i * per_req) % len(ds)
+        hi = min(lo + per_req, len(ds))
+        requests.append(Request(i, ds.clip_tokens[lo:hi],
+                                ds.context_tokens[lo:hi],
+                                ds.clip_mask[lo:hi]))
+    return requests
+
+
 def serve_service(args) -> None:
     """Run the fault-tolerant ``SimulationService`` front-end over the
     synthetic suite: requests carry per-request deadlines, admission can
@@ -169,9 +193,6 @@ def serve_service(args) -> None:
     from repro.configs import get_config
     from repro.core import predictor
     from repro.core import standardize as std_mod
-    from repro.data.dataset import BuildConfig, build_dataset
-    from repro.isa import progen
-    from repro.serving.engine import Request
     from repro.serving.service import ServiceSLA, SimulationService
 
     config = _build_engine_config(args)
@@ -181,26 +202,15 @@ def serve_service(args) -> None:
     vocab = std_mod.build_vocab()
     cfg = get_config("capsim").replace(dtype="float32")
     params = predictor.init_params(cfg, jax.random.PRNGKey(0))
-
-    names = list(progen.TABLE_II)[: args.n_benchmarks]
-    bcfg = BuildConfig(interval_size=config.interval_size, warmup=0,
-                       max_checkpoints=1, l_min=100,
-                       l_clip=config.l_clip, l_token=config.l_token)
-    ds = build_dataset(names, bcfg, vocab)
+    requests = service_requests(config, vocab, args.n_benchmarks,
+                                args.n_requests)
     sla = ServiceSLA(default_deadline_s=args.deadline_s,
                      watchdog_s=args.watchdog_s)
 
     metrics_server = _start_metrics(args)
     t0 = time.time()
     with SimulationService(params, cfg, config, sla=sla) as svc:
-        tickets = []
-        per_req = max(1, len(ds) // max(args.n_requests, 1))
-        for i in range(args.n_requests):
-            lo = (i * per_req) % len(ds)
-            hi = min(lo + per_req, len(ds))
-            tickets.append(svc.submit(Request(
-                i, ds.clip_tokens[lo:hi], ds.context_tokens[lo:hi],
-                ds.clip_mask[lo:hi])))
+        tickets = [svc.submit(r) for r in requests]
         results = [t.result(timeout=600) for t in tickets]
         stats = svc.stats()
     wall = time.time() - t0
@@ -372,6 +382,8 @@ def main() -> None:
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{args.mesh}").strip()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.arch == "capsim" and args.service:
         serve_service(args)
     elif args.arch == "capsim":

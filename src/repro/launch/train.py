@@ -29,6 +29,7 @@ from repro.configs import ShapeConfig, get_config, get_smoke_config
 from repro.distributed.fault_tolerance import ResilientTrainer
 from repro.distributed.sharding import (
     LOGICAL_RULES_PREDICTOR, LOGICAL_RULES_TRAIN, use_mesh_and_rules)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.training.train_loop import (
     TrainConfig, init_train_state, make_train_step)
@@ -243,6 +244,7 @@ def main() -> None:
                     help="append the other cores' <CORE>-tagged register "
                          "blocks to every clip's context matrix")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.arch != "capsim":
         train_lm(args)
     elif args.multicore:

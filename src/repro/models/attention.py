@@ -24,8 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import (axis_rules, compat_shard_map,
-                                        current_mesh, shard_logical)
+from repro.distributed.sharding import (axis_rules, current_mesh,
+                                        shard_logical)
 from repro.models.layers import ParamSpec, apply_rope, dense_spec, rms_norm
 
 
@@ -139,9 +139,9 @@ def sp_prefill_attention(q, k, v, cfg):
         return _causal_attention_chunked(q_loc, kb, vb, cfg.attn_chunk,
                                          q_start=m * s_loc)
 
-    fn = compat_shard_map(local, mesh=mesh,
-                          in_specs=(spec, spec, spec), out_specs=spec,
-                          check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec, spec, spec), out_specs=spec,
+                       check_vma=False)
     return fn(q, k, v)
 
 
@@ -220,7 +220,7 @@ def flash_decode(q, k_cache, v_cache, cache_pos, cfg):
     q_spec = P(batch_entry, None, None, None)
     kv_spec = P(batch_entry, seq_axes, None, None)
 
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         partial(_flash_decode_local, s_loc=s_loc, scale=scale,
                 seq_axes=seq_axes, axis_sizes=sizes),
         mesh=mesh,

@@ -28,8 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import (axis_rules, compat_shard_map,
-                                        current_mesh)
+from repro.distributed.sharding import axis_rules, current_mesh
 from repro.models.layers import ParamSpec
 
 
@@ -152,7 +151,7 @@ def moe_forward(params, x, cfg) -> Tuple[jax.Array, jax.Array, jax.Array]:
 
     batch_axes = axis_rules(("batch",), mesh=mesh)[0] if dp_axes else None
     tok_spec = P(batch_axes, None)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         partial(_moe_local, k=k, n_exp=E, e_loc=E // n_model, cap=cap,
                 dp_axes=dp_axes, act=cfg.activation),
         mesh=mesh,

@@ -13,7 +13,7 @@ from repro.configs import get_config
 from repro.core import context as ctx_mod
 from repro.core import predictor
 from repro.core.engine import (BatchedPredictor, SimulationEngine,
-                               bucket_sizes, predict_fn)
+                               bucket_sizes, predict_fn, reference_fn)
 from repro.core.engine_config import EngineConfig
 from repro.core.simulate import capsim_simulate
 from repro.core.standardize import ClipEncoder, build_vocab, encode_clip
@@ -122,3 +122,23 @@ def test_encode_clips_matches_encode_clip():
         np.testing.assert_array_equal(mask[i], m_ref)
     # memo hit rate: loopy traces collapse onto few standardized shapes
     assert len(enc._memo) < sum(len(c) for c in clips)
+
+
+def test_reference_step_is_f32_xla_attention_at_highest(params):
+    """The accuracy yardstick: even from a bf16 Pallas config it runs the
+    f32 XLA forward at highest matmul precision; on the CPU that is
+    bitwise the monolithic fp32 step."""
+    rng = np.random.RandomState(0)
+    batch = {"clip_tokens": rng.randint(0, VOCAB.size, (3, 32, 16)
+                                        ).astype(np.int32),
+             "context_tokens": rng.randint(0, VOCAB.size, (3, 360)
+                                           ).astype(np.int32),
+             "clip_mask": np.ones((3, 32), np.float32)}
+    ref = reference_fn(SMALL_CFG.replace(dtype="bfloat16",
+                                         attn_impl="pallas"))
+    text = ref.lower(params, batch).as_text()
+    assert "HIGHEST" in text and "DEFAULT" not in text
+    assert "tpu_custom_call" not in text
+    np.testing.assert_array_equal(np.asarray(ref(params, batch)),
+                                  np.asarray(predict_fn(SMALL_CFG)(params,
+                                                                   batch)))

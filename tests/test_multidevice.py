@@ -32,8 +32,8 @@ from repro.models.attention import (_causal_attention_chunked, flash_decode,
 from repro.models.layers import init_from_specs
 
 assert len(jax.devices()) == 8, jax.devices()
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rng = np.random.RandomState(0)
 
 # ---------------- MoE: shard_map EP vs meshless reference ----------------
@@ -93,6 +93,6 @@ def test_multidevice_numerics():
     r = subprocess.run([sys.executable, "-c", PROGRAM], capture_output=True,
                        text=True, timeout=500,
                        env={**__import__("os").environ,
-                            "PYTHONPATH": "src"})
+                            "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"})
     assert "ALL MULTIDEVICE CHECKS PASSED" in r.stdout, \
         f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"

@@ -10,6 +10,7 @@ import json
 import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -17,7 +18,9 @@ from repro.configs import get_config
 from repro.core import predictor
 from repro.core.engine_config import EngineConfig, ObservabilityConfig
 from repro.core.standardize import build_vocab
+from repro.launch import compile_cache
 from repro.obs import NULL_SPAN, MetricsRegistry, Observability, Tracer
+from repro.obs.compiles import compile_monitor
 from repro.obs.exporter import serve_metrics
 from repro.serving.engine import Request
 from repro.serving.faults import FaultInjector
@@ -251,7 +254,7 @@ def test_service_snapshot_roundtrip_and_stable_keys(params):
     assert list(d["tiers"]["rt"]) == [
         "name", "flushes", "clips", "demotions", "promotions",
         "nan_trips", "relerr_trips", "fault_trips", "watchdog_trips",
-        "persist_failures"]
+        "persist_failures", "spot_checks"]
     back = ServiceSnapshot.from_dict(json.loads(json.dumps(d)))
     assert back.to_dict() == d
     with pytest.raises(ValueError):
@@ -312,3 +315,71 @@ def test_faults_counter_lands_in_registry():
     assert inj.maybe("device_error")
     after = REGISTRY.value(FAULTS_INJECTED_TOTAL, kind="device_error")
     assert after == before + 1
+
+
+# --------------------------------------------------------------------------- #
+# Compile accounting + the persistent compile cache
+# --------------------------------------------------------------------------- #
+
+def test_compile_monitor_clocks_this_thread_only():
+    mon = compile_monitor()
+    assert compile_monitor() is mon            # one listener set per process
+    x = jnp.ones((17, 3))
+    c0 = mon.counts()["compiles"]
+    with mon.attach() as acct:
+        f = jax.jit(lambda a: jnp.tanh(a) * 2.0 + a.sum())
+        f(x).block_until_ready()
+        first = acct.seconds()
+        f(x).block_until_ready()               # cached: no trace, no compile
+        assert acct.seconds() == first
+        # another thread's compile is not charged to this thread
+        th = threading.Thread(target=lambda: jax.jit(
+            lambda a: a * 5.0 - 2.0)(x).block_until_ready())
+        th.start()
+        th.join()
+        assert acct.seconds() == first
+    assert first > 0
+    assert mon.counts()["compiles"] > c0
+
+
+@pytest.fixture
+def restore_cache_config():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    cc.reset_cache()
+    yield
+    for k, v in saved.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+
+
+def test_compile_cache_fixed_dir_and_repeat_hits(tmp_path, monkeypatch,
+                                                 restore_cache_config):
+    fixed = tmp_path / "fixed"
+    monkeypatch.delenv(compile_cache.CACHE_ENV, raising=False)
+    monkeypatch.setattr(compile_cache, "DEFAULT_CACHE_DIR", fixed)
+    assert compile_cache.enable_compile_cache() == str(fixed)
+    assert jax.config.jax_compilation_cache_dir == str(fixed)
+    mon = compile_monitor()
+    x = jnp.ones(11)
+    jax.jit(lambda a: jnp.cos(a) * 3.0 - 1.5)(x).block_until_ready()
+    assert any(fixed.iterdir())
+    hits = mon.counts()["cache_hits"]
+    # the same program from a fresh jit is read back, not recompiled
+    jax.jit(lambda a: jnp.cos(a) * 3.0 - 1.5)(x).block_until_ready()
+    assert mon.counts()["cache_hits"] > hits
+
+
+def test_compile_cache_leaves_env_dir_to_jax(tmp_path, monkeypatch,
+                                             restore_cache_config):
+    monkeypatch.setenv(compile_cache.CACHE_ENV, str(tmp_path / "env"))
+    monkeypatch.setattr(compile_cache, "DEFAULT_CACHE_DIR",
+                        tmp_path / "fixed")
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_compile_cache() == str(tmp_path / "env")
+    # JAX reads the variable itself at start-up; the helper sets no dir
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "fixed").exists()

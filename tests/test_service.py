@@ -265,3 +265,39 @@ def test_service_stats_shape(params):
                                  "monolithic"]
     assert st["tiers"]["fused_int8"]["clips"] == 4
     assert st["current_tier"] == "fused_int8"
+
+
+def test_service_watchdog_excludes_compile_time(params):
+    # a width no other test compiles: the first flush traces and compiles
+    # every program of the top rung, which outlasts this watchdog budget
+    cfg = SMALL_CFG.replace(d_ff=96)
+    p = predictor.init_params(cfg, jax.random.PRNGKey(1))
+    sla = _sla(watchdog_s=0.5, check_every=0)
+    with SimulationService(p, cfg, BASE, sla=sla) as svc:
+        res = svc.submit(_req(0)).result(timeout=300)
+        st = svc.stats()
+    assert res.service_seconds > sla.watchdog_s    # a cold, slow flush...
+    assert res.status == "ok" and res.tier == "fused_int8"   # ...served
+    assert all(t["watchdog_trips"] == 0 for t in st["tiers"].values())
+
+
+def test_service_spot_checks_against_reference(params):
+    sla = _sla(check_every=1)
+    with SimulationService(params, SMALL_CFG, BASE, sla=sla) as svc:
+        results = [svc.submit(_req(i)).result(timeout=300)
+                   for i in range(2)]
+        top = svc.tier_stats[0]
+        assert top.spot_checks == 2 and top.relerr_trips == 0
+    assert all(r.status == "ok" for r in results)
+
+
+def test_service_audit_holds_every_tier_to_its_tolerance(params):
+    svc = SimulationService(params, SMALL_CFG, BASE, sla=_sla())
+    errs = svc.audit(_req(0))
+    assert list(errs) == ["fused_int8", "fused", "rt", "monolithic"]
+    for tier, err in errs.items():
+        assert 0.0 <= err <= svc.sla.tier_tolerances[tier], (tier, err)
+    assert errs["monolithic"] < 1e-6   # the reference's own numerics
+    with svc:
+        with pytest.raises(RuntimeError, match="stopped"):
+            svc.audit(_req(0))
