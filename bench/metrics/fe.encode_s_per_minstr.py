@@ -1,0 +1,9 @@
+"""Host seconds turning traces into clips (``engine.tokenize`` and
+``engine.context`` spans) per million simulated instructions."""
+
+
+def read(r):
+    minstr = r.counter("capsim_frontend_instructions_total") / 1e6
+    if not minstr:
+        return None
+    return (r.span_s("engine.tokenize") + r.span_s("engine.context")) / minstr

@@ -1,0 +1,8 @@
+"""Puts the benchmark's harness and the system under test on the path."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT / "src", ROOT / "bench"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
