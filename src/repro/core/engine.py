@@ -438,12 +438,6 @@ class BatchedPredictor:
             "capsim_predictor_in_flight",
             "Un-retired device batches (the double buffer).",
             ("instance",)).labels(instance=self.instance)
-        self._h_occupancy = m.histogram(
-            "capsim_predictor_bucket_occupancy",
-            "Real-row share of each dispatched bucket.",
-            ("instance",),
-            buckets=(0.25, 0.5, 0.75, 0.9, 0.99, 1.0)).labels(
-                instance=self.instance)
         if fault_injector is None and config.faults:
             # deferred import: repro.serving imports this module
             from repro.serving.faults import FaultInjector
@@ -595,7 +589,8 @@ class BatchedPredictor:
             # host-side context dedup (~ms per batch): the fused step
             # attends over each row's unique tokens with multiplicity
             # weights instead of all M context rows
-            uniq, counts = std_mod.dedupe_context_tokens(ctx)
+            with self.obs.span("predict.dedup", instance=self.instance):
+                uniq, counts = std_mod.dedupe_context_tokens(ctx)
             batch = {"rt_idx": jnp.asarray(tok),
                      "ctx_uniq": jnp.asarray(uniq),
                      "ctx_count": jnp.asarray(counts),
@@ -620,7 +615,6 @@ class BatchedPredictor:
             self._batch_handles[shape] = handle
         handle.inc()
         self._c_pad.inc(shape - n_real)
-        self._h_occupancy.observe(n_real / shape)
         while len(self._pending) > self.max_in_flight:
             self._retire()
         self._g_in_flight.set(len(self._pending))
@@ -807,7 +801,8 @@ class SimulationEngine:
             # tree, so the bitwise params-identity contract holds within
             # the engine (and the RT store keys on the quantized bytes)
             from repro.core import quant
-            params = quant.quantize_dequant_params(params)
+            with self.obs.span("engine.quantize", instance=self.instance):
+                params = quant.quantize_dequant_params(params)
         self.params = params
         self.cfg = pred_mod.inference_config(cfg, config.precision)
         self.vocab = vocab

@@ -151,9 +151,10 @@ class ObservabilityConfig:
     The metrics registry is always on — it replaced the ad-hoc Stats
     accumulators, so it costs what they cost.  ``trace`` opts into the
     span tracer (a private ``repro.obs.Tracer`` ring of ``trace_ring``
-    spans, Chrome-trace exportable); disabled tracing allocates nothing
-    on the span path.  ``flight_dir`` opts into the degradation flight
-    recorder: the last ``flight_events`` structured events and
+    spans, Chrome-trace exportable); with it off, spans go to the
+    registry (and, while a JAX profiler session records, the profiler
+    trace) but not to a ring.  ``flight_dir`` opts into the degradation
+    flight recorder: the last ``flight_events`` structured events and
     ``flight_spans`` trace spans are frozen into an atomic postmortem
     JSON under that directory whenever the service demotes a tier, the
     watchdog abandons a flush, or a persist fault fires.

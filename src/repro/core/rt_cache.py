@@ -343,10 +343,11 @@ class RTCache:
         (n, L_clip) int32 RT row ids.  Dynamic rows are deduped before the
         encoder sees them; all-<PAD> (masked) slots land on row 0."""
         from repro.core.standardize import dedupe_token_rows
-        n, L, T = clip_tokens.shape
-        uniq, inv = dedupe_token_rows(clip_tokens.reshape(n * L, T))
-        ids = self.ensure_rows(uniq)
-        return ids[inv].reshape(n, L).astype(np.int32)
+        with self.obs.span("rt.index", instance=self.instance):
+            n, L, T = clip_tokens.shape
+            uniq, inv = dedupe_token_rows(clip_tokens.reshape(n * L, T))
+            ids = self.ensure_rows(uniq)
+            return ids[inv].reshape(n, L).astype(np.int32)
 
     def _flush(self, rows: np.ndarray, pending: Dict[bytes, int]) -> None:
         k = rows.shape[0]
@@ -368,7 +369,10 @@ class RTCache:
                 table = table.at[:lo].set(self._table[:lo])
             self._table = table
         self._table = self._table.at[lo:lo + k].set(rt)
-        self._table.block_until_ready()      # build time stays in stats
+        # build time stays in stats; the wait includes any predict
+        # batches already queued ahead of the encode pass on the device
+        with self.obs.span("rt.wait", instance=self.instance):
+            self._table.block_until_ready()
         self._index.update(pending)
         self._n += k
         self._c_encoded.inc(k)

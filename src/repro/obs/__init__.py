@@ -2,10 +2,11 @@
 
 :class:`Observability` is the bundle components hold.  Its
 :meth:`~Observability.span` primitive always times (the registry is the
-system of record — the Stats view classes read it back), and feeds the
-tracer ring only when tracing is enabled, so one ``with obs.span(...)``
-stanza replaces both the old ad-hoc ``time.time()`` accounting and the
-bench-only ``perf_counter`` breakdowns.
+system of record — the Stats view classes read it back), feeds the
+tracer ring when tracing is enabled, and, while a JAX profiler session
+records, opens a ``jax.profiler.TraceAnnotation`` under the span's name,
+so one ``with obs.span(...)`` stanza is the registry's seconds, the
+ring's timeline and the profiler trace's host event at once.
 
 Construction::
 
@@ -14,6 +15,11 @@ Construction::
         ...
     elapsed = sp.seconds          # same clock the registry recorded
 
+Every span gets a process-unique ``id`` and its parent's id: the span
+open on the same thread when it starts, or the ``parent=`` span given
+explicitly for work handed to another thread.  The ids ride along into
+the ring, the Chrome export, postmortems and the profiler's metadata.
+
 ``from_config(None)`` shares the process-global registry and the
 disabled global tracer; ``ObservabilityConfig(trace=True)`` gets a
 private enabled :class:`Tracer` the owner can dump with
@@ -21,17 +27,20 @@ private enabled :class:`Tracer` the owner can dump with
 """
 from __future__ import annotations
 
+import itertools
+import threading
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from .flight import POSTMORTEM_SCHEMA_VERSION, FlightRecorder
 from .metrics import (COUNTER, DEFAULT_TIME_BUCKETS, GAUGE, HISTOGRAM,
                       REGISTRY, MetricsRegistry, exp_buckets)
-from .trace import NULL_SPAN, TRACER, SpanRecord, Tracer
+from . import trace as _trace
+from .trace import TRACER, SpanRecord, Tracer
 
 __all__ = [
     "COUNTER", "GAUGE", "HISTOGRAM", "DEFAULT_TIME_BUCKETS", "REGISTRY",
-    "TRACER", "NULL_SPAN", "POSTMORTEM_SCHEMA_VERSION", "MetricsRegistry",
+    "TRACER", "POSTMORTEM_SCHEMA_VERSION", "MetricsRegistry",
     "Tracer", "SpanRecord", "FlightRecorder", "Observability",
     "exp_buckets", "SPAN_SECONDS_TOTAL", "SPAN_SECONDS_HIST",
 ]
@@ -39,29 +48,64 @@ __all__ = [
 SPAN_SECONDS_TOTAL = "capsim_span_seconds_total"
 SPAN_SECONDS_HIST = "capsim_span_seconds"
 
+_SPAN_IDS = itertools.count(1)           # 0 means "no parent"
+_OPEN = threading.local()                # .stack: this thread's open spans
+
+
+def _open_spans() -> List["_ObsSpan"]:
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = []
+    return stack
+
 
 class _ObsSpan:
-    """Times one span; writes the registry always, the tracer if on."""
+    """Times one span; writes the registry always, the tracer if on, the
+    profiler while a session records."""
 
-    __slots__ = ("_obs", "_name", "_instance", "_args", "_start", "seconds")
+    __slots__ = ("_obs", "_name", "_instance", "_args", "_parent", "_start",
+                 "_stack", "_annot", "id", "parent_id", "depth", "seconds")
 
     def __init__(self, obs: "Observability", name: str, instance: str,
-                 args: Optional[Dict[str, object]]):
+                 args: Optional[Dict[str, object]],
+                 parent: Optional["_ObsSpan"]):
         self._obs = obs
         self._name = name
         self._instance = instance
         self._args = args
+        self._parent = parent
+        self._annot = None
+        self.id = 0
+        self.parent_id = 0
+        self.depth = 0
         self.seconds = 0.0
 
     def __enter__(self):
+        stack = _open_spans()
+        parent = self._parent if self._parent is not None else (
+            stack[-1] if stack else None)
+        self.id = next(_SPAN_IDS)
+        if parent is not None:
+            self.parent_id = parent.id
+            self.depth = parent.depth + 1
+        stack.append(self)
+        self._stack = stack
+        if _trace.profiler_active():
+            self._annot = _trace.annotation(self._name, self.id,
+                                            self.parent_id, self._args)
+            self._annot.__enter__()
         self._start = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur_ns = time.perf_counter_ns() - self._start
+        if self._annot is not None:
+            self._annot.__exit__(None, None, None)
+            self._annot = None
+        self._stack.remove(self)
         self.seconds = dur_ns * 1e-9
-        self._obs._record_span(self._name, self._instance, self._start,
-                               dur_ns, self._args)
+        self._obs._record(self._name, self._instance, self._start, dur_ns,
+                          self._args, self.id, self.parent_id, self.depth)
         return False
 
 
@@ -97,11 +141,26 @@ class Observability:
 
     # -- span primitive -----------------------------------------------------
     def span(self, name: str, instance: str = "",
-             args: Optional[Dict[str, object]] = None) -> _ObsSpan:
-        return _ObsSpan(self, name, instance, args)
+             args: Optional[Dict[str, object]] = None, *,
+             parent: Optional[_ObsSpan] = None) -> _ObsSpan:
+        """Context manager timing one span.  ``parent`` names the span
+        that caused this one when it runs on another thread; by default
+        the parent is the span open on this thread."""
+        return _ObsSpan(self, name, instance, args, parent)
 
-    def _record_span(self, name: str, instance: str, start_ns: int,
-                     dur_ns: int, args: Optional[Dict[str, object]]) -> None:
+    def record_span(self, name: str, start_ns: int, dur_ns: int, *,
+                    instance: str = "",
+                    args: Optional[Dict[str, object]] = None) -> None:
+        """Record a span timed elsewhere (``start_ns`` on the
+        ``perf_counter_ns`` clock), with no parent, into the registry and
+        the ring.  The profiler cannot take an event that began in the
+        past, so it does not see this one."""
+        self._record(name, instance, start_ns, dur_ns, args,
+                     next(_SPAN_IDS), 0, 0)
+
+    def _record(self, name: str, instance: str, start_ns: int,
+                dur_ns: int, args: Optional[Dict[str, object]],
+                span_id: int, parent_id: int, depth: int) -> None:
         key = (name, instance)
         handles = self._handles.get(key)
         if handles is None:
@@ -115,7 +174,9 @@ class Observability:
             targs = dict(args) if args else {}
             if instance:
                 targs["instance"] = instance
-            self.tracer.record(name, start_ns, dur_ns, args=targs or None)
+            self.tracer.record(name, start_ns, dur_ns, args=targs or None,
+                               span_id=span_id, parent_id=parent_id,
+                               depth=depth)
 
     # -- events -------------------------------------------------------------
     def event(self, kind: str, **data: object) -> None:
@@ -123,7 +184,11 @@ class Observability:
         if self.flight is not None:
             self.flight.record(kind, **data)
         if self.tracer.enabled:
-            self.tracer.instant(kind, args=dict(data) or None)
+            stack = _open_spans()
+            self.tracer.instant(
+                kind, args=dict(data) or None,
+                parent_id=stack[-1].id if stack else 0,
+                depth=stack[-1].depth + 1 if stack else 0)
 
     def postmortem(self, reason: str,
                    state: Optional[dict] = None) -> Optional[str]:

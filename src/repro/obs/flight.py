@@ -66,6 +66,7 @@ class FlightRecorder:
                     "name": rec.name, "cat": rec.cat,
                     "start_ns": rec.start_ns, "dur_ns": rec.dur_ns,
                     "tid": rec.tid, "depth": rec.depth,
+                    "id": rec.span_id, "parent": rec.parent_id,
                     "args": rec.args})
         post: Dict[str, object] = {
             "schema_version": POSTMORTEM_SCHEMA_VERSION,

@@ -1,135 +1,127 @@
-"""Low-overhead span tracer with Chrome-trace-event / Perfetto export.
+"""Span ring with Chrome-trace-event / Perfetto export, and the bridge to
+the JAX profiler.
 
-Spans time with :func:`time.perf_counter_ns`, track nesting depth via
-thread-local span stacks, and land in a bounded ring buffer
+:class:`Tracer` keeps finished spans in a bounded ring buffer
 (``deque(maxlen=ring_size)``) so a long-running service never grows
-without bound.  When the tracer is disabled, :meth:`Tracer.span`
-returns the shared :data:`NULL_SPAN` singleton — no allocation, no
-clock read — which is what keeps always-present instrumentation out of
-the hot path's profile.
+without bound.  Spans are timed by ``Observability.span``, which hands
+each finished one to :meth:`Tracer.record` with its id, its parent's id
+and its nesting depth; a disabled tracer drops them.
 
 ``export_chrome()`` emits the Chrome trace-event JSON format (complete
 ``"ph": "X"`` events, microsecond timestamps); open the file at
 https://ui.perfetto.dev to get a zoomable per-thread timeline.
+
+:func:`profiler_active` and :func:`annotation` are the one place that
+touches the JAX profiler: while a profiler session records, every span
+also opens a ``jax.profiler.TraceAnnotation`` under its own name, so the
+span lands in the ``.xplane.pb`` trace on the profiler's clock next to
+the device operations.  JAX is imported on first use; without it no
+session can be active and spans stay on the host clock alone.
 """
 from __future__ import annotations
 
 import json
+import numbers
 import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
 
-
-class _NullSpan:
-    """Shared no-op span for the disabled path (identity-stable)."""
-
-    __slots__ = ()
-    seconds = 0.0
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
+_TRACE_ANNOTATION = None       # jax.profiler.TraceAnnotation, or False
 
 
-NULL_SPAN = _NullSpan()
+def _trace_annotation():
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION
+
+
+def profiler_active() -> bool:
+    """True while a JAX profiler session records host events (jaxlib's
+    ``TraceMe.is_enabled()``, ~0.1 us)."""
+    ann = _trace_annotation()
+    return bool(ann) and ann.is_enabled()
+
+
+def annotation(name: str, span_id: int, parent_id: int,
+               args: Optional[Dict[str, object]] = None):
+    """An un-entered profiler annotation for one span.  Its metadata are
+    the span's ``id``, its ``parent`` and the integer-valued ``args``:
+    the profiler cuts a string value at its first comma, so anything
+    but an integer is left out."""
+    meta = {"id": span_id, "parent": parent_id}
+    if args:
+        for k, v in args.items():
+            if isinstance(v, numbers.Integral):
+                meta[k] = int(v)
+    return _trace_annotation()(name, **meta)
 
 
 class SpanRecord:
-    """One finished span (or instant event when ``dur_ns`` is None)."""
+    """One finished span (or instant event when ``dur_ns`` is None).
 
-    __slots__ = ("name", "cat", "start_ns", "dur_ns", "tid", "depth", "args")
+    ``span_id`` is unique in the process; ``parent_id`` is the id of the
+    span that caused it (0 for none), on this thread or, for a span
+    opened with an explicit parent, on another."""
+
+    __slots__ = ("name", "cat", "start_ns", "dur_ns", "tid", "depth",
+                 "span_id", "parent_id", "args")
 
     def __init__(self, name: str, cat: str, start_ns: int,
                  dur_ns: Optional[int], tid: int, depth: int,
-                 args: Optional[Dict[str, object]]):
+                 args: Optional[Dict[str, object]], span_id: int = 0,
+                 parent_id: int = 0):
         self.name = name
         self.cat = cat
         self.start_ns = start_ns
         self.dur_ns = dur_ns
         self.tid = tid
         self.depth = depth
+        self.span_id = span_id
+        self.parent_id = parent_id
         self.args = args
 
 
-class _LiveSpan:
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_start", "seconds")
-
-    def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Optional[Dict[str, object]]):
-        self._tracer = tracer
-        self._name = name
-        self._cat = cat
-        self._args = args
-        self.seconds = 0.0
-
-    def __enter__(self):
-        self._tracer._stack().append(self)
-        self._start = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, *exc):
-        end = time.perf_counter_ns()
-        stack = self._tracer._stack()
-        if stack and stack[-1] is self:
-            stack.pop()
-        dur = end - self._start
-        self.seconds = dur * 1e-9
-        self._tracer._append(SpanRecord(
-            self._name, self._cat, self._start, dur,
-            threading.get_ident(), len(stack), self._args))
-        return False
-
-
 class Tracer:
-    """Span tracer writing into a bounded ring buffer."""
+    """Ring buffer of finished spans and instant events."""
 
     def __init__(self, ring_size: int = 4096, enabled: bool = False):
         self.enabled = bool(enabled)
         self._ring: deque = deque(maxlen=int(ring_size))
         self._lock = threading.Lock()
-        self._tls = threading.local()
         self._t0_ns = time.perf_counter_ns()
-
-    # -- internals ----------------------------------------------------------
-    def _stack(self) -> list:
-        stack = getattr(self._tls, "stack", None)
-        if stack is None:
-            stack = self._tls.stack = []
-        return stack
 
     def _append(self, rec: SpanRecord) -> None:
         with self._lock:
             self._ring.append(rec)
 
     # -- recording ----------------------------------------------------------
-    def span(self, name: str, cat: str = "span",
-             args: Optional[Dict[str, object]] = None):
-        """Context manager timing one span; NULL_SPAN when disabled."""
-        if not self.enabled:
-            return NULL_SPAN
-        return _LiveSpan(self, name, cat, args)
-
     def record(self, name: str, start_ns: int, dur_ns: int,
                cat: str = "span",
-               args: Optional[Dict[str, object]] = None) -> None:
-        """Append an already-timed span (the Observability fast path)."""
+               args: Optional[Dict[str, object]] = None, *,
+               span_id: int = 0, parent_id: int = 0,
+               depth: int = 0) -> None:
+        """Append an already-timed span."""
         if not self.enabled:
             return
         self._append(SpanRecord(name, cat, start_ns, dur_ns,
-                                threading.get_ident(),
-                                len(self._stack()), args))
+                                threading.get_ident(), depth, args,
+                                span_id, parent_id))
 
     def instant(self, name: str, cat: str = "event",
-                args: Optional[Dict[str, object]] = None) -> None:
+                args: Optional[Dict[str, object]] = None, *,
+                parent_id: int = 0, depth: int = 0) -> None:
         """Record a zero-duration instant event (tier trips, faults)."""
         if not self.enabled:
             return
         self._append(SpanRecord(name, cat, time.perf_counter_ns(), None,
-                                threading.get_ident(),
-                                len(self._stack()), args))
+                                threading.get_ident(), depth, args, 0,
+                                parent_id))
 
     # -- control / export ---------------------------------------------------
     def set_enabled(self, enabled: bool) -> bool:
@@ -159,6 +151,8 @@ class Tracer:
                 ev["dur"] = rec.dur_ns / 1e3
             args = dict(rec.args) if rec.args else {}
             args["depth"] = rec.depth
+            args["id"] = rec.span_id
+            args["parent"] = rec.parent_id
             ev["args"] = args
             events.append(ev)
         return {"traceEvents": events, "displayTimeUnit": "ms"}

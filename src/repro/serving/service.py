@@ -470,6 +470,7 @@ class SimulationService:
         self._queue: Deque[_QueuedRequest] = deque()
         self._queued_clips = 0
         self._rate: Optional[float] = None        # EWMA clips/sec
+        self._flush_seq = 0                       # windows collected
         self._running = False
         self._draining = False
         self._worker: Optional[threading.Thread] = None
@@ -555,6 +556,12 @@ class SimulationService:
         ticket; a shed request's ticket is already resolved with the
         typed ``overloaded`` result — callers never block to learn they
         were rejected."""
+        with self.obs.span("svc.submit", instance=self.instance,
+                           args={"request": req.request_id}):
+            return self._submit(req, deadline_s)
+
+    def _submit(self, req: Request,
+                deadline_s: Optional[float]) -> ServiceTicket:
         validate_request(req, self.config,
                          (self.config.l_clip, self.config.l_token))
         n_clips = req.clip_tokens.shape[0]
@@ -627,28 +634,43 @@ class SimulationService:
 
     def _serve_loop(self) -> None:
         while True:
-            with self._cond:
+            with self.obs.span("svc.wait", instance=self.instance), \
+                    self._cond:
                 while not self._queue:
                     if not self._running:
                         return
                     self._cond.wait(0.05)
                 if not self._running and not self._draining:
                     return
-                batch = self._collect_window()
+                self._flush_seq += 1
+                flush = self._flush_seq
+                batch = self._collect_window(flush)
             if batch:
-                self._serve_batch(batch)
+                clips = sum(qr.ticket.n_clips for qr in batch)
+                with self.obs.span(
+                        "svc.flush", instance=self.instance,
+                        args={"flush": flush, "requests": len(batch),
+                              "clips": clips}) as flush_span:
+                    self._serve_batch(batch, flush, flush_span)
 
-    def _collect_window(self) -> List[_QueuedRequest]:
+    def _collect_window(self, flush: int) -> List[_QueuedRequest]:
         """Pop one continuous-batching window off the queue (lock held):
         everything queued, up to ``max_flush_clips``.  Requests already
         past their deadline resolve here — typed, without burning a
-        flush on work nobody is waiting for."""
+        flush on work nobody is waiting for.  Each request's time in the
+        queue is recorded as its ``svc.queue`` span."""
         now = time.time()
+        now_ns = time.perf_counter_ns()
         window: List[_QueuedRequest] = []
         clips = 0
         while self._queue and clips < self.sla.max_flush_clips:
             qr = self._queue.popleft()
             self._queued_clips -= qr.ticket.n_clips
+            queued_ns = int((now - qr.arrival) * 1e9)
+            self.obs.record_span(
+                "svc.queue", now_ns - queued_ns, queued_ns,
+                instance=self.instance,
+                args={"request": qr.req.request_id, "flush": flush})
             if now > qr.deadline:
                 self._finish(qr, ServiceResult(
                     request_id=qr.req.request_id, status=STATUS_DEADLINE,
@@ -662,9 +684,11 @@ class SimulationService:
         self._update_queue_gauges()
         return window
 
-    def _serve_batch(self, batch: List[_QueuedRequest]) -> None:
-        """Serve one window, walking down the tier ladder on faults.
-        Every request in the window ends resolved, whatever happens."""
+    def _serve_batch(self, batch: List[_QueuedRequest], flush: int,
+                     flush_span) -> None:
+        """Serve one window (flush number ``flush``, timed by
+        ``flush_span``), walking down the tier ladder on faults.  Every
+        request in the window ends resolved, whatever happens."""
         t_start = time.time()
         attempts = 0
         max_attempts = len(self._tiers) + 2
@@ -693,7 +717,9 @@ class SimulationService:
             tier = self._tiers[idx]
             ts = self.tier_stats[idx]
             try:
-                times, run_s = self._flush_watchdogged(tier, batch)
+                times, run_s = self._flush_watchdogged(
+                    tier, batch, flush_span,
+                    {"flush": flush, "attempt": attempts, "tier": idx})
             except FlushTimeout:
                 ts.inc("watchdog_trips")
                 tier.invalidate_backend()
@@ -719,7 +745,9 @@ class SimulationService:
                     and self.sla.check_every > 0
                     and self._n_flushes % self.sla.check_every == 0):
                 ts.inc("spot_checks")
-                err = self._spot_check(tier, batch)
+                with self.obs.span("svc.spot_check", instance=self.instance,
+                                   args={"flush": flush}):
+                    err = self._spot_check(tier, batch)
                 tol = self.sla.tier_tolerances.get(
                     tier.name, float("inf"))
                 if err is not None and err > tol:
@@ -739,15 +767,17 @@ class SimulationService:
             status = STATUS_OK if idx == 0 else STATUS_DEGRADED
             done_t = time.time()
             off = 0
-            for qr in batch:
-                k = qr.ticket.n_clips
-                self._finish(qr, ServiceResult(
-                    request_id=qr.req.request_id, status=status,
-                    total_cycles=float(times[off:off + k].sum()),
-                    tier=tier.name, n_clips=k,
-                    queue_seconds=t_start - qr.arrival,
-                    service_seconds=done_t - t_start))
-                off += k
+            with self.obs.span("svc.resolve", instance=self.instance,
+                               args={"flush": flush}):
+                for qr in batch:
+                    k = qr.ticket.n_clips
+                    self._finish(qr, ServiceResult(
+                        request_id=qr.req.request_id, status=status,
+                        total_cycles=float(times[off:off + k].sum()),
+                        tier=tier.name, n_clips=k,
+                        queue_seconds=t_start - qr.arrival,
+                        service_seconds=done_t - t_start))
+                    off += k
             promoted = self._ctrl.on_healthy()
             if promoted is not None:
                 self.tier_stats[promoted].inc("promotions")
@@ -795,10 +825,12 @@ class SimulationService:
         self.obs.postmortem(f"demote_{reason}", state=state)
 
     def _flush_watchdogged(self, tier: _Tier,
-                           batch: Sequence[_QueuedRequest]
+                           batch: Sequence[_QueuedRequest], flush_span,
+                           span_args: Dict[str, int]
                            ) -> Tuple[np.ndarray, float]:
-        """Run one flush on a watchdog thread.  Returns (times, run
-        seconds: the flush's wall time less its trace/compile time);
+        """Run one flush on a watchdog thread, as an ``svc.attempt`` span
+        (``span_args``) whose parent is ``flush_span``.  Returns (times,
+        run seconds: the flush's wall time less its trace/compile time);
         raises ``FlushTimeout`` once the run time passes
         ``sla.watchdog_s`` (the stuck thread is abandoned — see the
         module docstring)."""
@@ -813,7 +845,9 @@ class SimulationService:
 
         def _run():
             try:
-                with compile_monitor().attach(compiling):
+                with self.obs.span("svc.attempt", instance=self.instance,
+                                   args=span_args, parent=flush_span), \
+                        compile_monitor().attach(compiling):
                     backend = tier.backend()
                     backend.reset_context_width()
                     if self.config.sampling is not None:

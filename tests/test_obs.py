@@ -19,7 +19,8 @@ from repro.core import predictor
 from repro.core.engine_config import EngineConfig, ObservabilityConfig
 from repro.core.standardize import build_vocab
 from repro.launch import compile_cache
-from repro.obs import NULL_SPAN, MetricsRegistry, Observability, Tracer
+from repro.obs import MetricsRegistry, Observability, Tracer
+from repro.obs import trace as obs_trace
 from repro.obs.compiles import compile_monitor
 from repro.obs.exporter import serve_metrics
 from repro.serving.engine import Request
@@ -180,17 +181,25 @@ def test_exporter_serves_registry():
 # --------------------------------------------------------------------------- #
 
 def test_disabled_tracer_is_free():
-    """Disabled tracing returns THE null span singleton — no per-call
-    allocation, no ring append."""
+    """Spans on a disabled tracer still time into the registry, but
+    nothing reaches the ring — spans, instants or pre-timed records."""
+    m = MetricsRegistry()
     tr = Tracer(enabled=False)
-    assert tr.span("x") is NULL_SPAN
-    assert tr.span("y", args={"a": 1}) is NULL_SPAN
-    with tr.span("z") as sp:
+    obs = Observability(metrics=m, tracer=tr)
+    with obs.span("x"):
         pass
-    assert sp.seconds == 0.0
-    tr.instant("ev")
+    with obs.span("y", args={"a": 1}) as sp:
+        pass
+    assert sp.seconds > 0 and sp.id > 0
+    obs.event("ev")
+    obs.record_span("pre", 0, 100)
     tr.record("pre", 0, 100)
+    tr.instant("ev")
     assert tr.spans() == []
+    assert m.value("capsim_span_seconds_total", span="y", instance="") \
+        == pytest.approx(sp.seconds)
+    assert m.value("capsim_span_seconds_total", span="pre", instance="") \
+        == pytest.approx(1e-7)
 
 
 def test_ring_wraparound_keeps_last_n():
@@ -203,20 +212,217 @@ def test_ring_wraparound_keeps_last_n():
 
 
 def test_chrome_export_shape():
-    tr = Tracer(enabled=True)
-    with tr.span("outer", args={"k": "v"}):
-        with tr.span("inner"):
-            pass
-    tr.instant("mark")
-    doc = tr.export_chrome()
+    obs = Observability(metrics=MetricsRegistry(),
+                        tracer=Tracer(enabled=True))
+    with obs.span("outer", args={"k": "v"}) as outer_sp:
+        with obs.span("inner") as inner_sp:
+            obs.event("mark")
+    doc = obs.tracer.export_chrome()
     events = doc["traceEvents"]
     names = [e["name"] for e in events]
-    assert names == ["inner", "outer", "mark"]   # inner closes first
-    outer = events[1]
+    assert names == ["mark", "inner", "outer"]   # inner closes first
+    mark, inner, outer = events
     assert outer["ph"] == "X" and outer["args"]["k"] == "v"
-    assert events[0]["args"]["depth"] == 1       # nested under outer
-    assert events[2]["ph"] == "i"
+    assert inner["args"]["depth"] == 1           # nested under outer
+    assert inner["args"]["parent"] == outer["args"]["id"] == outer_sp.id
+    assert outer["args"]["parent"] == 0
+    assert mark["ph"] == "i" and mark["args"]["parent"] == inner_sp.id
     json.dumps(doc)                              # must be serializable
+
+
+def test_nested_spans_carry_parent_ids():
+    obs = Observability(metrics=MetricsRegistry(),
+                        tracer=Tracer(enabled=True))
+    with obs.span("a") as a:
+        with obs.span("b") as b:
+            with obs.span("c") as c:
+                pass
+        with obs.span("d") as d:
+            pass
+    with obs.span("e") as e:
+        pass
+    assert len({a.id, b.id, c.id, d.id, e.id}) == 5
+    assert (a.parent_id, b.parent_id, c.parent_id, d.parent_id,
+            e.parent_id) == (0, a.id, b.id, a.id, 0)
+    assert (a.depth, b.depth, c.depth, d.depth) == (0, 1, 2, 1)
+    recs = {r.name: r for r in obs.tracer.spans()}
+    assert recs["c"].span_id == c.id and recs["c"].parent_id == b.id
+    assert recs["c"].depth == 2
+
+
+def test_explicit_parent_crosses_threads():
+    """A span opened on another thread with ``parent=`` hangs under the
+    given span, and its own children hang under it."""
+    obs = Observability(metrics=MetricsRegistry(),
+                        tracer=Tracer(enabled=True))
+    got = {}
+
+    def child(parent):
+        with obs.span("worker.child", parent=parent) as ch:
+            with obs.span("worker.grandchild") as gc:
+                pass
+        with obs.span("worker.orphan") as orphan:
+            pass
+        got.update(ch=ch, gc=gc, orphan=orphan)
+
+    with obs.span("main.parent") as parent:
+        th = threading.Thread(target=child, args=(parent,))
+        th.start()
+        th.join()
+    assert got["ch"].parent_id == parent.id and got["ch"].depth == 1
+    assert got["gc"].parent_id == got["ch"].id and got["gc"].depth == 2
+    assert got["orphan"].parent_id == 0      # the thread's own stack
+    recs = {r.name: r for r in obs.tracer.spans()}
+    assert recs["worker.child"].tid != recs["main.parent"].tid
+
+
+def test_record_span_has_an_id_and_no_parent():
+    """A span timed elsewhere did not run inside the span open when it
+    is recorded."""
+    obs = Observability(metrics=MetricsRegistry(),
+                        tracer=Tracer(enabled=True))
+    with obs.span("open") as sp:
+        obs.record_span("timed", 10, 5, args={"request": 3})
+    recs = {r.name: r for r in obs.tracer.spans()}
+    assert recs["timed"].span_id > sp.id and recs["timed"].parent_id == 0
+    assert recs["timed"].args == {"request": 3}
+    assert (recs["timed"].start_ns, recs["timed"].dur_ns) == (10, 5)
+    assert obs.metrics.value("capsim_span_seconds_total",
+                             span="timed", instance="") \
+        == pytest.approx(5e-9)
+
+
+@pytest.fixture
+def counted_annotations(monkeypatch):
+    """Counts the profiler annotations the span primitive builds."""
+    from jax.profiler import TraceAnnotation
+    built = []
+
+    class Counted(TraceAnnotation):
+        def __init__(self, name, **meta):
+            built.append((name, meta))
+            super().__init__(name, **meta)
+
+    monkeypatch.setattr(obs_trace, "_TRACE_ANNOTATION", Counted)
+    return built
+
+
+def test_no_profiler_session_builds_no_annotation(counted_annotations):
+    m = MetricsRegistry()
+    obs = Observability(metrics=m, tracer=Tracer(enabled=False))
+    assert not obs_trace.profiler_active()
+    for _ in range(3):
+        with obs.span("quiet.work", instance="q0", args={"n": 1}) as sp:
+            pass
+    assert counted_annotations == []
+    assert sp.seconds > 0
+    [(_, (total, count))] = m.collect("capsim_span_seconds")
+    assert count == 3 and total > 0
+    assert m.value("capsim_span_seconds_total", span="quiet.work",
+                   instance="q0") == pytest.approx(total)
+
+
+def test_profiler_session_gets_one_annotation_per_span(counted_annotations,
+                                                       tmp_path):
+    obs = Observability(metrics=MetricsRegistry(),
+                        tracer=Tracer(enabled=False))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert obs_trace.profiler_active()
+        with obs.span("svc.flush", args={"flush": 3, "name": "a,b",
+                                         "rate": 0.5}) as outer:
+            with obs.span("rt.index"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    assert not obs_trace.profiler_active()
+    assert [n for n, _ in counted_annotations] == ["svc.flush", "rt.index"]
+    # integers only: the profiler cuts strings at a comma
+    assert counted_annotations[0][1] == {"id": outer.id, "parent": 0,
+                                         "flush": 3}
+    inner_meta = counted_annotations[1][1]
+    assert set(inner_meta) == {"id", "parent"}
+    assert inner_meta["parent"] == outer.id < inner_meta["id"]
+
+
+def test_rt_wait_lies_inside_rt_build(params):
+    from repro.core.rt_cache import RTCache
+    obs = Observability(metrics=MetricsRegistry(),
+                        tracer=Tracer(enabled=True))
+    cache = RTCache(params, SMALL_CFG, SMALL_CFG.clip_tokens, obs=obs)
+    rows = np.random.RandomState(0).randint(
+        0, VOCAB.size, (5, SMALL_CFG.clip_tokens)).astype(np.int32)
+    cache.ensure_rows(rows)
+    recs = obs.tracer.spans()
+    [build] = [r for r in recs if r.name == "rt.build"]
+    [wait] = [r for r in recs if r.name == "rt.wait"]
+    assert wait.parent_id == build.span_id
+    assert build.start_ns <= wait.start_ns
+    assert wait.start_ns + wait.dur_ns <= build.start_ns + build.dur_ns
+    assert obs.metrics.value("capsim_span_seconds_total", span="rt.wait",
+                             instance=cache.instance) > 0
+
+
+def test_bucket_occupancy_family_is_gone(params):
+    from repro.core.engine import BatchedPredictor
+    m = MetricsRegistry()
+    pred = BatchedPredictor(params, SMALL_CFG,
+                            config=EngineConfig(batch_size=8,
+                                                rt_cache=False),
+                            obs=Observability(metrics=m))
+    r = _req(0, n=3)
+    pred.add(r.clip_tokens, r.context_tokens, r.clip_mask)
+    assert pred.drain().shape == (3,)
+    families = set(m.snapshot())
+    assert "capsim_predictor_pad_rows_total" in families
+    assert "capsim_predictor_batches_total" in families
+    assert not any("occupancy" in f for f in families)
+
+
+def test_service_flush_spans_share_flush_and_request_ids(params):
+    """One flush of one request: every span of the request path is
+    recorded, and the ids tie them to their flush and request."""
+    config = EngineConfig(batch_size=8, observability=ObservabilityConfig(
+        trace=True, trace_ring=4096))
+    sla = ServiceSLA(watchdog_s=300.0, check_every=1)
+    with SimulationService(params, SMALL_CFG, config, sla=sla) as svc:
+        tickets = [svc.submit(_req(10, n=3))]
+        assert tickets[0].result(timeout=300).status == "ok"
+    recs = svc.obs.tracer.spans()
+    by_id = {r.span_id: r for r in recs}
+    names = {r.name for r in recs}
+    assert {"svc.submit", "svc.queue", "svc.wait", "svc.flush",
+            "svc.attempt", "rt.index", "predict.dedup", "svc.resolve",
+            "svc.spot_check", "rt.build", "predict.dispatch"} <= names
+
+    def ancestors(rec):
+        while rec.parent_id:
+            rec = by_id[rec.parent_id]
+            yield rec
+
+    [flush] = [r for r in recs if r.name == "svc.flush"]
+    fid = flush.args["flush"]
+    assert flush.args["requests"] == 1 and flush.args["clips"] == 3
+    [attempt] = [r for r in recs if r.name == "svc.attempt"]
+    assert attempt.parent_id == flush.span_id
+    assert attempt.tid != flush.tid            # the watchdog's thread
+    assert attempt.args == {"flush": fid, "attempt": 1, "tier": 0,
+                            "instance": svc.instance}
+    for name in ("rt.index", "predict.dedup"):
+        # the attempt's own and the spot check's re-run on the worker
+        under = [list(ancestors(r)) for r in recs if r.name == name]
+        assert all(flush in up for up in under), name
+        assert any(attempt in up for up in under), name
+    for name in ("svc.resolve", "svc.spot_check"):
+        [rec] = [r for r in recs if r.name == name]
+        assert rec.args["flush"] == fid and rec.parent_id == flush.span_id
+    rids = {t.request_id for t in tickets}
+    submits = [r for r in recs if r.name == "svc.submit"]
+    queues = [r for r in recs if r.name == "svc.queue"]
+    assert {r.args["request"] for r in submits} == rids
+    assert {r.args["request"] for r in queues} == rids
+    assert all(r.args["flush"] == fid and r.parent_id == 0 for r in queues)
+    assert all(r.dur_ns > 0 for r in queues)
 
 
 def test_obs_span_records_metrics_and_trace(tmp_path):
