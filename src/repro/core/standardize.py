@@ -325,10 +325,45 @@ def dedupe_token_rows(rows: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     Token ids are non-negative, so when an all-<PAD> (zero) row is present
     it lexicographically sorts to local id 0 — the convention the RT
     cache's pad slot and ``data.dataset.indexed_clips`` both rely on.
+
+    The result is bitwise ``np.unique(rows, axis=0, return_inverse=True)``
+    (rows in lexicographic order), computed on packed integer keys: the
+    tokens, shifted by the minimum, are packed most significant first at
+    the bit width their observed range needs into uint64 words, whose
+    lexicographic order is the rows'.  ``np.unique(axis=0)`` would compare
+    each row as a structured record field by field, an order of magnitude
+    slower on a served request's 25,600 rows.
     """
-    uniq, inv = np.unique(rows, axis=0, return_inverse=True)
-    return (np.ascontiguousarray(uniq, np.int32),
-            inv.reshape(rows.shape[0]).astype(np.int32))
+    k, l_token = rows.shape
+    if k == 0:
+        return np.ascontiguousarray(rows, np.int32), np.zeros(0, np.int32)
+    shifted = rows.astype(np.int64)
+    shifted -= shifted.min()
+    bits = max(1, int(shifted.max()).bit_length())
+    per_word = 64 // bits
+    tokens = shifted.view(np.uint64)
+    words = []                              # most significant word first
+    for lo in range(0, l_token, per_word):
+        word = tokens[:, lo].copy()
+        for t in range(lo + 1, min(lo + per_word, l_token)):
+            word <<= np.uint64(bits)
+            word |= tokens[:, t]
+        words.append(word)
+    if len(words) == 1:
+        _, first, inv = np.unique(words[0], return_index=True,
+                                  return_inverse=True)
+    else:
+        order = np.lexsort(words[::-1])         # first word is primary
+        start = np.zeros(k, bool)
+        start[0] = True
+        for word in words:
+            srt = word[order]
+            start[1:] |= srt[1:] != srt[:-1]
+        first = order[start]
+        inv = np.empty(k, np.int64)
+        inv[order] = np.cumsum(start) - 1
+    return (np.ascontiguousarray(rows[first], np.int32),
+            inv.astype(np.int32))
 
 
 def dedup_bucket(n: int, cap: int) -> int:
