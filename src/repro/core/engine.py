@@ -688,6 +688,12 @@ class BatchedPredictor:
         return preds
 
 
+def _clip_rows(ctx_all: np.ndarray, n_clips: int) -> np.ndarray:
+    """One context row per clip: the snapshot before the clip's first
+    instruction (the last one for clips past the final snapshot)."""
+    return ctx_all[np.minimum(np.arange(n_clips), len(ctx_all) - 1)]
+
+
 @dataclasses.dataclass
 class _Job:
     bench: object                     # Benchmark or (multicore) core label
@@ -795,6 +801,10 @@ class SimulationEngine:
             "capsim_frontend_clips_total",
             "Clips sliced/tokenized by the front-end.",
             ("instance",)).labels(instance=self.instance)
+        self._c_peer_clips = self.obs.metrics.counter(
+            "capsim_peer_context_clips_total",
+            "Clips fed with the peer-channel context layout.",
+            ("instance",)).labels(instance=self.instance)
         if config.precision == "int8":
             # per-channel weight fake-quantization at engine build: the
             # cache, plan and predict step all see the SAME quantized
@@ -869,11 +879,16 @@ class SimulationEngine:
     def _feed_trace(self, trace, token_table, static_ids,
                     pred: BatchedPredictor, job: _Job,
                     core_id: Optional[int] = None,
-                    sink: Optional[list] = None) -> int:
+                    sink: Optional[list] = None,
+                    peer_snapshots: Optional[np.ndarray] = None) -> int:
         """Tokenize + context one interval trace and enqueue its clips —
         the shared interval body of the single-core and multicore paths
         (``core_id=None`` keeps the single-core context layout bit for
-        bit).  Returns the clip count enqueued.
+        bit).  With ``peer_snapshots`` (core ``core_id``'s whole-machine
+        captures from ``run_multicore(..., peer_snapshots=True)``) each
+        clip gets the peer-channel layout, ``peer_context_tokens``, the
+        same rows the multicore training build packs.  Returns the clip
+        count enqueued.
 
         With ``sink`` (the fusion path) nothing reaches the predictor
         yet: clip tensors land in the sink together with their
@@ -894,10 +909,17 @@ class SimulationEngine:
             n_clips = tok.shape[0]             # slice_fixed partition
 
         with self.obs.span("engine.context", instance=self.instance):
-            ctx_all = ctx_mod.context_tokens_from_matrix(
-                trace.snapshots, self.vocab, core_id=core_id)
-            rows = np.minimum(np.arange(n_clips), len(ctx_all) - 1)
-            ctx = ctx_all[rows]
+            if peer_snapshots is None:
+                ctx = _clip_rows(ctx_mod.context_tokens_from_matrix(
+                    trace.snapshots, self.vocab, core_id=core_id), n_clips)
+            else:
+                with self.obs.span("engine.peer_context",
+                                   instance=self.instance,
+                                   args={"clips": n_clips}):
+                    ctx = _clip_rows(ctx_mod.peer_context_tokens(
+                        trace.snapshots, peer_snapshots, core_id,
+                        self.vocab), n_clips)
+                self._c_peer_clips.inc(n_clips)
 
         job.n_clips += n_clips
         self._c_fe_clips.inc(n_clips)
@@ -1119,6 +1141,19 @@ class SimulationEngine:
 
     # ------------------------------ multicore ------------------------------ #
 
+    def _multicore_interval(self, mb: multicore.MulticoreBenchmark,
+                            cprogs, states, quantum: int
+                            ) -> multicore.MulticoreTrace:
+        """One interval of ``mb`` under the round-robin schedule; with
+        ``config.peer_channels`` (and more than one core) the trace also
+        carries the whole-machine captures the peer layout needs."""
+        with self.obs.span("engine.interpret", instance=self.instance):
+            return multicore.run_multicore(
+                cprogs, self.interval_size, states,
+                snapshot_every=self.l_min, quantum=quantum,
+                peer_snapshots=self.config.peer_channels
+                and mb.n_cores > 1)
+
     def run_multicore(self,
                       mbenches: Sequence[multicore.MulticoreBenchmark], *,
                       quantum: Optional[int] = None
@@ -1135,15 +1170,13 @@ class SimulationEngine:
         reference path (``bench_speed.run_multicore_bench``) mirrors, so
         equality is bitwise, per core and summed.  Each core's context
         matrices carry its ``core_id`` channel
-        (``context_tokens_from_matrix(..., core_id=c)``); the oracle is
-        ``timing.simulate_multicore`` over the recorded commit
+        (``context_tokens_from_matrix(..., core_id=c)``); with
+        ``config.peer_channels`` and more than one core they are the
+        peer-channel layout instead (M = n_cores x 369: the own block,
+        then every other core's block as of the quantum's start).  The
+        oracle is ``timing.simulate_multicore`` over the recorded commit
         interleave.
         """
-        if self.config.peer_channels:
-            raise NotImplementedError(
-                "peer_channels serving is reserved (ROADMAP item 8): the "
-                "peer-context training channels are not wired into the "
-                "trace engine's context layout yet")
         if quantum is None:
             quantum = (self.config.quantum
                        if self.config.quantum is not None
@@ -1188,13 +1221,12 @@ class SimulationEngine:
                                                 states, quantum=quantum)
                 n_ckp = min(mb.ckp_num, self.max_checkpoints)
                 for _ in range(n_ckp):
-                    with self.obs.span("engine.interpret",
-                                       instance=self.instance):
-                        mtrace = multicore.run_multicore(
-                            cprogs, self.interval_size, states,
-                            snapshot_every=self.l_min, quantum=quantum)
+                    mtrace = self._multicore_interval(mb, cprogs, states,
+                                                      quantum)
                     if len(mtrace) == 0:
                         break
+                    peers = (mtrace.peer_snapshots
+                             or [None] * len(mtrace.cores))
                     for c, trace in enumerate(mtrace.cores):
                         if not len(trace):
                             continue
@@ -1202,7 +1234,8 @@ class SimulationEngine:
                             trace, token_tables[c],
                             static_ids[c] if static_ids is not None
                             else None,
-                            pred, jobs[c], core_id=c)
+                            pred, jobs[c], core_id=c,
+                            peer_snapshots=peers[c])
                         segments.append((jobs[c], n_clips))
                     if self.with_oracle:
                         with self.obs.span("engine.oracle",
@@ -1304,13 +1337,12 @@ class SimulationEngine:
                                                 states, quantum=quantum)
                 n_ckp = min(mb.ckp_num, self.max_checkpoints)
                 for _ in range(n_ckp):
-                    with self.obs.span("engine.interpret",
-                                       instance=self.instance):
-                        mtrace = multicore.run_multicore(
-                            cprogs, self.interval_size, states,
-                            snapshot_every=self.l_min, quantum=quantum)
+                    mtrace = self._multicore_interval(mb, cprogs, states,
+                                                      quantum)
                     if len(mtrace) == 0:
                         break
+                    peers = (mtrace.peer_snapshots
+                             or [None] * len(mtrace.cores))
                     for c, trace in enumerate(mtrace.cores):
                         if not len(trace):
                             continue
@@ -1318,7 +1350,8 @@ class SimulationEngine:
                             trace, token_tables[c],
                             static_ids[c] if static_ids is not None
                             else None,
-                            pred, jobs[c], core_id=c, sink=sinks[c])
+                            pred, jobs[c], core_id=c, sink=sinks[c],
+                            peer_snapshots=peers[c])
                     if self.with_oracle:
                         with self.obs.span("engine.oracle",
                                            instance=self.instance) as osp:

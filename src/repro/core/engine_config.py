@@ -33,8 +33,10 @@ Field groups:
                 ``l_min``, ``l_clip``, ``l_token``, ``with_oracle``.
   multicore     ``multicore`` (N cores; 0 = single-core suite),
                 ``quantum`` (None = scheduler default),
-                ``peer_channels`` (peer-context serving — reserved,
-                ROADMAP item 8).
+                ``peer_channels`` (peer-channel context: each clip's
+                context also holds every other core's register block,
+                M = N x 369, the layout the multicore training build
+                packs; ``run_multicore`` and its sampled path serve it).
   faults        ``faults`` — chaos-engineering fault-injection spec, a
                 tuple of ``(kind, rate)`` pairs (``FAULT_KINDS`` below)
                 consumed by ``repro.serving.faults.FaultInjector`` and

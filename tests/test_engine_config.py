@@ -195,15 +195,6 @@ def test_predictor_engine_legacy_kwargs_raise(params, vocab):
 
 # ------------------------------ entry points ------------------------------ #
 
-def test_peer_channels_reserved(params, vocab):
-    ec = EC.replace(multicore=2, peer_channels=True)
-    engine = SimulationEngine.from_config(params, SMALL_CFG, vocab, ec)
-    mb = multicore.build_multicore_benchmark(
-        list(multicore.MULTICORE_NAMES)[0], 2)
-    with pytest.raises(NotImplementedError, match="peer_channels"):
-        engine.run_multicore([mb])
-
-
 def test_quantum_flows_from_config(params, vocab):
     mb = multicore.build_multicore_benchmark(
         list(multicore.MULTICORE_NAMES)[0], 2)

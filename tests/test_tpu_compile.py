@@ -122,3 +122,32 @@ def test_fused_serving_step_fits_one_chip(one_chip, monkeypatch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < V5E_HBM_BYTES, f"{used / 1e9:.2f} GB > 16 GB"
+
+
+@pytest.mark.parametrize("m_ctx", [369, 16 * 369])
+def test_rt_step_fits_one_chip(one_chip, monkeypatch, m_ctx):
+    # the engine's rt step (``forward_cached``: RT-table gather, block
+    # encoder with the flash kernel, head) at the engine's batch of 256
+    # over the core-tagged context (M 369) and the 16-core peer-channel
+    # context (M 16 x 369 = 5,904)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = predictor.inference_config(
+        get_config("capsim").replace(dtype="float32"), "int8")
+    assert cfg.attn_impl == "pallas"
+    batch, capacity = EngineConfig().batch_size, 4096
+    l_clip, e = EngineConfig().l_clip, cfg.d_model
+    params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                          predictor.abstract_params(cfg))
+    table = _spec((capacity, e), jnp.float32, one_chip)
+    step_batch = {
+        "rt_idx": _spec((batch, l_clip), jnp.int32, one_chip),
+        "context_tokens": _spec((batch, m_ctx), jnp.int32, one_chip),
+        "clip_mask": _spec((batch, l_clip), jnp.float32, one_chip)}
+    compiled = _compile(
+        lambda p, t, b: predictor.forward_cached(p, t, b, cfg),
+        params, table, step_batch)
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    print(f"rt step M={m_ctx}: {used / 1e9:.3f} GB")
+    assert used < V5E_HBM_BYTES, f"{used / 1e9:.2f} GB > 16 GB"
