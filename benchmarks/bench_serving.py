@@ -48,9 +48,10 @@ if __package__ in (None, ""):   # direct `python benchmarks/bench_serving.py`
 from benchmarks.common import (SERVING_BENCH_SCHEMA_VERSION, bench_cfg,
                                full_cfg, get_mixed_dataset)
 from repro.core import predictor
+from repro.core.engine import BatchedPredictor
 from repro.core.engine_config import EngineConfig, ObservabilityConfig
 from repro.launch.compile_cache import enable_compile_cache
-from repro.serving.engine import PredictorEngine, Request
+from repro.serving.engine import Request
 from repro.serving.service import (TIER_TRANSITIONS_TOTAL, ServiceSLA,
                                    SimulationService)
 
@@ -154,12 +155,18 @@ def reference_totals(params, cfg, config: EngineConfig,
     a bounded sample: the monolithic path is the slow rung by design
     (that is the whole point of the ladder), so gating every full-scale
     request here would dwarf the bench itself."""
-    eng = PredictorEngine(params, cfg, config.replace(
+    mono = BatchedPredictor(params, cfg, config=config.replace(
         precision=None, fused_serving=False, rt_cache=False,
         rt_store_dir=None, faults=()))
     for r in reqs:
-        eng.submit(r)
-    return {r.request_id: r.total_cycles for r in eng.flush()}
+        mono.add(r.clip_tokens, r.context_tokens, r.clip_mask)
+    times = mono.drain()
+    totals, off = {}, 0
+    for r in reqs:
+        k = r.clip_tokens.shape[0]
+        totals[r.request_id] = float(times[off:off + k].sum())
+        off += k
+    return totals
 
 
 def drive_phase(svc: SimulationService, reqs: List[Request],

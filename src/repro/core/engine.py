@@ -696,19 +696,27 @@ def _clip_rows(ctx_all: np.ndarray, n_clips: int) -> np.ndarray:
 
 @dataclasses.dataclass
 class _Job:
-    bench: object                     # Benchmark or (multicore) core label
-    offset: int = 0                   # first clip index in the global pool
+    bench: object                     # Benchmark or MulticoreBenchmark
+    name: str                         # result name (``<bench>#c<k>``)
+    # flattened job index: the sampler's and the bootstrap's draw key
+    key: int
+    # (tok, ctx, mask, features) per interval under config.sampling;
+    # None on the full path, where clips go straight into the pool
+    sink: Optional[list] = None
+    # (features, strata, sampled) once the job's sample is fed
+    plan: Optional[tuple] = None
     n_clips: int = 0
     n_intervals: int = 0
     n_instructions: int = 0
     oracle_cycles: float = 0.0
     oracle_seconds: float = 0.0
     func_seconds: float = 0.0
-    # multicore demux: (bench, core) clips land in per-checkpoint
-    # segments interleaved across cores, so predictions accumulate
-    # segment-by-segment instead of as one contiguous pool slice
+    # demux: one float(segment.sum()) per pool segment the job fed,
+    # added in feed order (or the fused estimate under sampling)
     predicted_cycles: float = 0.0
-    name: str = ""
+    n_fed: int = 0                    # pool rows the job fed
+    # the PredictionReport fields fuse_predictions sets (sampling only)
+    fused: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -771,9 +779,9 @@ class SimulationEngine:
     cfg, vocab, EngineConfig(...))`` (or the equivalent ``config=``
     keyword) is the single way every knob — trace scale, batching,
     precision, RT cache, multicore N and the device mesh — reaches the
-    engine; ``capsim_simulate``/``capsim_simulate_multicore``, serving
-    ``PredictorEngine`` and ``launch/serve.py`` are all thin wrappers
-    over it.  A non-empty ``mesh_shape`` shards every predict dispatch
+    engine; ``capsim_simulate``/``capsim_simulate_multicore`` and
+    ``launch/serve.py`` are thin wrappers over it.  A non-empty
+    ``mesh_shape`` shards every predict dispatch
     AND every RT-cache encode pass across the data mesh, bitwise equal
     to the unsharded engine.  ``config.sampling`` switches runs to the
     analytical-ML fusion path: only a stratified sample of each
@@ -879,7 +887,6 @@ class SimulationEngine:
     def _feed_trace(self, trace, token_table, static_ids,
                     pred: BatchedPredictor, job: _Job,
                     core_id: Optional[int] = None,
-                    sink: Optional[list] = None,
                     peer_snapshots: Optional[np.ndarray] = None) -> int:
         """Tokenize + context one interval trace and enqueue its clips —
         the shared interval body of the single-core and multicore paths
@@ -890,9 +897,9 @@ class SimulationEngine:
         same rows the multicore training build packs.  Returns the clip
         count enqueued.
 
-        With ``sink`` (the fusion path) nothing reaches the predictor
-        yet: clip tensors land in the sink together with their
-        analytical feature rows, and the caller feeds only the
+        With a ``job.sink`` (the fusion path) nothing reaches the
+        predictor yet: clip tensors land in the sink together with their
+        analytical feature rows, and ``_feed_sample`` feeds only the
         stratified sample once the job's trace is complete."""
         n = len(trace)
         job.n_intervals += 1
@@ -923,56 +930,56 @@ class SimulationEngine:
 
         job.n_clips += n_clips
         self._c_fe_clips.inc(n_clips)
-        if sink is not None:
+        if job.sink is not None:
             with self.obs.span("engine.analytical",
                                instance=self.instance):
                 feats = analytical.clip_features(trace, self.l_min,
                                                  self.timing_params)
             assert feats.shape[0] == n_clips, \
                 "analytical windows must mirror the clip partition"
-            sink.append((tok, ctx, mask, feats))
+            job.sink.append((tok, ctx, mask, feats))
         elif static_ids is not None:
             pred.add_indexed(tok, ctx, mask)
         else:
             pred.add(tok, ctx, mask)
         return n_clips
 
-    def _feed_sample(self, pred: BatchedPredictor, sink: list,
-                     job: _Job, job_key: int):
+    def _feed_sample(self, pred: BatchedPredictor, job: _Job) -> int:
         """Stratify one job's collected clips, select the sample, and
         feed ONLY those rows to the predictor (preserving clip order,
         so cross-benchmark pipelining survives: the device crunches
         this job's sample while the next job's functional sim runs).
 
-        Returns the per-job fusion plan ``(features, strata, sampled)``
-        the post-drain demux hands to ``fuse_predictions``."""
+        Keeps the fusion plan ``(features, strata, sampled)`` on the job
+        for the post-drain ``fuse_predictions`` and returns the number
+        of rows fed."""
         scfg = self.config.sampling
-        if sink:
-            tok = np.concatenate([s[0] for s in sink])
-            ctx = np.concatenate([s[1] for s in sink])
-            mask = np.concatenate([s[2] for s in sink])
-            feats = np.concatenate([s[3] for s in sink])
+        if job.sink:
+            tok, ctx, mask, feats = (np.concatenate(col)
+                                     for col in zip(*job.sink))
         else:
             feats = np.zeros((0, analytical.N_FEATURES), np.float64)
+        job.sink.clear()
         strata = analytical.stratify(feats, scfg.strata)
         sampled, _ = sampler_mod.stratified_sample(
             strata, scfg.fraction, scfg.min_clips_per_stratum,
-            scfg.seed, key=job_key)
+            scfg.seed, key=job.key)
         if sampled.shape[0]:
             if self._rt_cache is not None:
                 pred.add_indexed(tok[sampled], ctx[sampled],
                                  mask[sampled])
             else:
                 pred.add(tok[sampled], ctx[sampled], mask[sampled])
-        return feats, strata, sampled
+        job.plan = (feats, strata, sampled)
+        return int(sampled.shape[0])
 
     def _functional(self, bench: progen.Benchmark, pred: BatchedPredictor,
-                    job: _Job, sink: Optional[list] = None) -> None:
+                    job: _Job) -> None:
         """Columnar functional sim + slice + tokenize one benchmark,
         feeding clips straight into the (asynchronously consuming)
-        predictor.  Tokens/contexts are bitwise identical to the object
-        path (``ClipEncoder`` over ``slice_fixed`` clips).  With
-        ``sink`` the clips collect there instead (fusion path)."""
+        predictor, or into ``job.sink`` on the fusion path.
+        Tokens/contexts are bitwise identical to the object path
+        (``ClipEncoder`` over ``slice_fixed`` clips)."""
         cprog = bench.compiled()
         token_table = cprog.token_table(self.vocab, self.l_token)
         static_ids = None
@@ -994,8 +1001,7 @@ class SimulationEngine:
                     snapshot_every=self.l_min)
             if not len(trace):
                 break
-            self._feed_trace(trace, token_table, static_ids, pred, job,
-                             sink=sink)
+            self._feed_trace(trace, token_table, static_ids, pred, job)
             if self.with_oracle:
                 with self.obs.span("engine.oracle",
                                    instance=self.instance) as osp:
@@ -1003,141 +1009,115 @@ class SimulationEngine:
                         trace, self.timing_params)
                 job.oracle_seconds += osp.seconds
 
-    def run(self, benches: Optional[Sequence[progen.Benchmark]] = None
-            ) -> List[SimResult]:
-        """Drain the queue (plus ``benches``) and return one ``SimResult``
-        per benchmark, in submission order."""
-        jobs = [_Job(b) for b in self._queue]
-        self._queue = []
-        if benches is not None:
-            jobs.extend(_Job(b) for b in benches)
-        if self.config.sampling is not None:
-            return self._run_sampled(jobs)
+    def _start_pass(self) -> Tuple[BatchedPredictor, RTCacheStats]:
+        """A run's fresh front-end stats and pooled predictor, and the
+        RT-cache stats whose build seconds each job subtracts."""
         self.frontend_stats = FrontendStats(self.obs, self.instance)
         pred = BatchedPredictor(self.params, self.cfg, config=self.config,
                                 rt_cache=self._rt_cache,
                                 fault_injector=self._faults, obs=self.obs)
         rt_stats = (self._rt_cache.stats if self._rt_cache is not None
                     else RTCacheStats())
-        offset = 0
+        return pred, rt_stats
+
+    def _new_job(self, bench, name: str, key: int) -> _Job:
+        return _Job(bench, name, key,
+                    sink=[] if self.config.sampling is not None else None)
+
+    def _finish_pass(self, pred: BatchedPredictor, rt_stats: RTCacheStats,
+                     jobs: Sequence[_Job],
+                     segments: Sequence[Tuple[_Job, int]]
+                     ) -> List[SimResult]:
+        """The one epilogue of ``run`` and ``run_multicore``: drain the
+        pool once, persist the RT table, and demux.  ``segments`` lists
+        the pool's rows in feed order as (job, rows).  A job's total is
+        one ``float(segment.sum())`` per segment, added in feed order;
+        under sampling a job fed one segment (its sample), and its total
+        is ``fuse_predictions`` over those rows.  Returns one
+        ``SimResult`` per job, in ``jobs`` order."""
+        preds = pred.drain()
+        if self._rt_cache is not None:
+            self._rt_cache.persist()          # no-op without a store_dir
+        self.last_stats = pred.stats
+        self.last_rt_stats = (rt_stats.freeze()
+                              if self._rt_cache is not None else None)
+        total = sum(n for _, n in segments)
+        assert preds.shape[0] == total == pred.stats.n_predicted, \
+            "clip accounting mismatch between pool and predictions"
+        scfg = self.config.sampling
+        off = 0
+        for job, n in segments:
+            rows = preds[off:off + n]
+            off += n
+            job.n_fed += n
+            if job.plan is None:
+                job.predicted_cycles += float(rows.sum())
+                continue
+            rep = analytical.fuse_predictions(
+                *job.plan, rows,
+                bootstrap_resamples=scfg.bootstrap_resamples,
+                seed=scfg.seed, key=job.key)
+            job.predicted_cycles = rep.total_cycles
+            job.fused = dict(cycles_ci=rep.cycles_ci,
+                             clips_predicted=rep.clips_predicted,
+                             clips_extrapolated=rep.clips_extrapolated,
+                             clip_provenance=rep.clip_provenance)
+
+        total_fed = max(total, 1)
+        return [SimResult(
+            name=job.name,
+            n_intervals=job.n_intervals,
+            n_instructions=job.n_instructions,
+            n_clips=job.n_clips,
+            predicted_cycles=job.predicted_cycles,
+            oracle_cycles=job.oracle_cycles if self.with_oracle else None,
+            func_seconds=job.func_seconds,
+            predict_seconds=pred.stats.predict_seconds
+            * (job.n_fed / total_fed),
+            oracle_seconds=job.oracle_seconds if self.with_oracle
+            else None,
+            **job.fused) for job in jobs]
+
+    def run(self, benches: Optional[Sequence[progen.Benchmark]] = None
+            ) -> List[SimResult]:
+        """Drain the queue (plus ``benches``) and return one ``SimResult``
+        per benchmark, in submission order.
+
+        One loop for both paths: program by program, the functional sim
+        feeds the pooled predictor, and one ``drain`` ends the run.  A
+        program's total is one ``float(rows.sum())`` over its contiguous
+        pool rows.  Under ``config.sampling`` a program's clips and their
+        analytical features collect first; once its trace is complete
+        only the stratified sample feeds the pool, and the total is
+        ``analytical.fuse_predictions`` (ridge residual extrapolation of
+        the rest, bootstrap CI) keyed by the program's index.  At
+        ``fraction=1.0`` every clip is sampled in order, and the total is
+        bitwise the unsampled one."""
+        benches = self._queue + list(benches or ())
+        self._queue = []
+        jobs = [self._new_job(b, b.name, k) for k, b in enumerate(benches)]
+        pred, rt_stats = self._start_pass()
+        segments: List[Tuple[_Job, int]] = []
         for job in jobs:
-            job.offset = offset
             d0 = pred.stats.dispatch_seconds
             b0 = rt_stats.build_seconds
             with self.obs.span("engine.job", instance=self.instance,
-                               args={"bench": job.bench.name}) as jsp:
+                               args={"bench": job.name}) as jsp:
                 self._functional(job.bench, pred, job)
+                n_fed = (job.n_clips if job.sink is None
+                         else self._feed_sample(pred, job))
+            segments.append((job, n_fed))
             # dispatch (and any blocking retire) and the RT-cache build
             # overlap the functional window; subtract both so device
             # predict time isn't counted twice
             job.func_seconds = (jsp.seconds - job.oracle_seconds
                                 - (pred.stats.dispatch_seconds - d0)
                                 - (rt_stats.build_seconds - b0))
-            offset = job.offset + job.n_clips
-        preds = pred.drain()
-        if self._rt_cache is not None:
-            self._rt_cache.persist()          # no-op without a store_dir
-        self.last_stats = pred.stats
-        self.last_rt_stats = (rt_stats.freeze()
-                              if self._rt_cache is not None else None)
-        assert preds.shape[0] == offset == pred.stats.n_predicted, \
-            "clip accounting mismatch between pool and predictions"
-
-        results = []
-        total_clips = max(offset, 1)
-        for job in jobs:
-            mine = preds[job.offset:job.offset + job.n_clips]
-            share = job.n_clips / total_clips
-            results.append(SimResult(
-                name=job.bench.name,
-                n_intervals=job.n_intervals,
-                n_instructions=job.n_instructions,
-                n_clips=job.n_clips,
-                predicted_cycles=float(mine.sum()),
-                oracle_cycles=job.oracle_cycles if self.with_oracle
-                else None,
-                func_seconds=job.func_seconds,
-                predict_seconds=pred.stats.predict_seconds * share,
-                oracle_seconds=job.oracle_seconds if self.with_oracle
-                else None))
-        return results
+        return self._finish_pass(pred, rt_stats, jobs, segments)
 
     def simulate(self, bench: progen.Benchmark) -> SimResult:
         """Single-benchmark convenience path (``capsim_simulate``)."""
         return self.run([bench])[0]
-
-    def _run_sampled(self, jobs: List[_Job]) -> List[SimResult]:
-        """Fusion path of ``run()``: per benchmark, collect every clip's
-        tensors + analytical features, stratify on the analytical cycle
-        estimate, run ONLY the stratified sample through the predictor,
-        then extrapolate the rest with a ridge residual fit and a
-        bootstrap CI (``analytical.fuse_predictions``).
-
-        At ``fraction=1.0`` every clip is "sampled" in original order,
-        the fit never runs, and the total is the plain ``float(sum())``
-        over the same prediction rows the unsampled path sums — bitwise
-        equal by the batch-composition-independence contract."""
-        scfg = self.config.sampling
-        self.frontend_stats = FrontendStats(self.obs, self.instance)
-        pred = BatchedPredictor(self.params, self.cfg, config=self.config,
-                                rt_cache=self._rt_cache,
-                                fault_injector=self._faults, obs=self.obs)
-        rt_stats = (self._rt_cache.stats if self._rt_cache is not None
-                    else RTCacheStats())
-        plans = []                    # (features, strata, sampled) per job
-        offset = 0
-        for j, job in enumerate(jobs):
-            sink: list = []
-            d0 = pred.stats.dispatch_seconds
-            b0 = rt_stats.build_seconds
-            with self.obs.span("engine.job", instance=self.instance,
-                               args={"bench": job.bench.name}) as jsp:
-                self._functional(job.bench, pred, job, sink=sink)
-                feats, strata, sampled = self._feed_sample(pred, sink,
-                                                           job, j)
-            job.func_seconds = (jsp.seconds - job.oracle_seconds
-                                - (pred.stats.dispatch_seconds - d0)
-                                - (rt_stats.build_seconds - b0))
-            job.offset = offset
-            offset += int(sampled.shape[0])
-            plans.append((feats, strata, sampled))
-        preds = pred.drain()
-        if self._rt_cache is not None:
-            self._rt_cache.persist()          # no-op without a store_dir
-        self.last_stats = pred.stats
-        self.last_rt_stats = (rt_stats.freeze()
-                              if self._rt_cache is not None else None)
-        assert preds.shape[0] == offset == pred.stats.n_predicted, \
-            "clip accounting mismatch between sample and predictions"
-
-        results = []
-        total_sampled = max(offset, 1)
-        for j, (job, (feats, strata, sampled)) in enumerate(
-                zip(jobs, plans)):
-            n_samp = int(sampled.shape[0])
-            mine = preds[job.offset:job.offset + n_samp]
-            rep = analytical.fuse_predictions(
-                feats, strata, sampled, mine,
-                bootstrap_resamples=scfg.bootstrap_resamples,
-                seed=scfg.seed, key=j)
-            share = n_samp / total_sampled
-            results.append(SimResult(
-                name=job.bench.name,
-                n_intervals=job.n_intervals,
-                n_instructions=job.n_instructions,
-                n_clips=job.n_clips,
-                predicted_cycles=rep.total_cycles,
-                oracle_cycles=job.oracle_cycles if self.with_oracle
-                else None,
-                func_seconds=job.func_seconds,
-                predict_seconds=pred.stats.predict_seconds * share,
-                oracle_seconds=job.oracle_seconds if self.with_oracle
-                else None,
-                cycles_ci=rep.cycles_ci,
-                clips_predicted=rep.clips_predicted,
-                clips_extrapolated=rep.clips_extrapolated,
-                clip_provenance=rep.clip_provenance))
-        return results
 
     # ------------------------------ multicore ------------------------------ #
 
@@ -1163,14 +1143,18 @@ class SimulationEngine:
         ``BatchedPredictor`` + shared ``RTCache`` -> demuxed per-core
         ``SimResult``s summed into per-benchmark cycles.
 
-        Clips arrive in per-(core, checkpoint) segments interleaved
-        across cores, so demux walks the recorded segment list; per-core
-        predicted cycles accumulate one ``float(segment.sum())`` per
-        checkpoint — the exact accumulation order the sequential
-        reference path (``bench_speed.run_multicore_bench``) mirrors, so
-        equality is bitwise, per core and summed.  Each core's context
-        matrices carry its ``core_id`` channel
-        (``context_tokens_from_matrix(..., core_id=c)``); with
+        One loop for both paths.  Without sampling, clips arrive in
+        per-(core, checkpoint) segments, checkpoint by checkpoint with
+        cores in order, and a core's predicted cycles accumulate one
+        ``float(segment.sum())`` per checkpoint — the exact accumulation
+        order of the per-core sequential reference, so equality is
+        bitwise, per core and summed.  Under ``config.sampling`` each
+        core's clips (all checkpoints) collect in its sink; once the
+        benchmark's checkpoints are done the per-core stratified samples
+        feed the pool in core order, and each core's total is
+        ``fuse_predictions`` keyed by the flattened (benchmark, core)
+        counter.  Each core's context matrices carry its ``core_id``
+        channel (``context_tokens_from_matrix(..., core_id=c)``); with
         ``config.peer_channels`` and more than one core they are the
         peer-channel layout instead (M = n_cores x 369: the own block,
         then every other core's block as of the quantum's start).  The
@@ -1181,21 +1165,14 @@ class SimulationEngine:
             quantum = (self.config.quantum
                        if self.config.quantum is not None
                        else multicore.DEFAULT_QUANTUM)
-        if self.config.sampling is not None:
-            return self._run_multicore_sampled(mbenches, quantum)
-        self.frontend_stats = FrontendStats(self.obs, self.instance)
-        pred = BatchedPredictor(self.params, self.cfg, config=self.config,
-                                rt_cache=self._rt_cache,
-                                fault_injector=self._faults, obs=self.obs)
-        rt_stats = (self._rt_cache.stats if self._rt_cache is not None
-                    else RTCacheStats())
-        all_jobs: List[List[_Job]] = []
+        pred, rt_stats = self._start_pass()
+        all_jobs: List[_Job] = []
         segments: List[Tuple[_Job, int]] = []
         for mb in mbenches:
             cprogs = mb.compiled()
             token_tables = [cp.token_table(self.vocab, self.l_token)
                             for cp in cprogs]
-            static_ids = None
+            static_ids = [None] * mb.n_cores
             if self._rt_cache is not None:
                 # all cores of one program share identical token tables
                 # (immediates collapse to <CONST>), so rows dedupe to one
@@ -1205,9 +1182,9 @@ class SimulationEngine:
                         tt, keys=cp.token_row_keys(self.vocab,
                                                    self.l_token))
                     for cp, tt in zip(cprogs, token_tables)]
-            jobs = [_Job(bench=mb, name=f"{mb.name}#c{c}")
+            jobs = [self._new_job(mb, f"{mb.name}#c{c}", len(all_jobs) + c)
                     for c in range(mb.n_cores)]
-            all_jobs.append(jobs)
+            all_jobs.extend(jobs)
             states = mb.fresh_states()
             d0 = pred.stats.dispatch_seconds
             b0 = rt_stats.build_seconds
@@ -1231,12 +1208,10 @@ class SimulationEngine:
                         if not len(trace):
                             continue
                         n_clips = self._feed_trace(
-                            trace, token_tables[c],
-                            static_ids[c] if static_ids is not None
-                            else None,
-                            pred, jobs[c], core_id=c,
-                            peer_snapshots=peers[c])
-                        segments.append((jobs[c], n_clips))
+                            trace, token_tables[c], static_ids[c], pred,
+                            jobs[c], core_id=c, peer_snapshots=peers[c])
+                        if jobs[c].sink is None:
+                            segments.append((jobs[c], n_clips))
                     if self.with_oracle:
                         with self.obs.span("engine.oracle",
                                            instance=self.instance) as osp:
@@ -1248,6 +1223,9 @@ class SimulationEngine:
                         for c, cyc in enumerate(totals):
                             jobs[c].oracle_cycles += cyc
                             jobs[c].oracle_seconds += dt / mb.n_cores
+                for job in jobs:
+                    if job.sink is not None:
+                        segments.append((job, self._feed_sample(pred, job)))
             mb_seconds = (jsp.seconds - oracle_s
                           - (pred.stats.dispatch_seconds - d0)
                           - (rt_stats.build_seconds - b0))
@@ -1255,170 +1233,8 @@ class SimulationEngine:
             for job in jobs:
                 job.func_seconds = mb_seconds * (job.n_clips / mb_clips)
 
-        preds = pred.drain()
-        if self._rt_cache is not None:
-            self._rt_cache.persist()          # no-op without a store_dir
-        self.last_stats = pred.stats
-        self.last_rt_stats = (rt_stats.freeze()
-                              if self._rt_cache is not None else None)
-        total = sum(n for _, n in segments)
-        assert preds.shape[0] == total == pred.stats.n_predicted, \
-            "clip accounting mismatch between shards and predictions"
-        off = 0
-        for job, n in segments:
-            job.predicted_cycles += float(preds[off:off + n].sum())
-            off += n
-
-        results = []
-        total_clips = max(total, 1)
-        for mb, jobs in zip(mbenches, all_jobs):
-            cores = [SimResult(
-                name=job.name,
-                n_intervals=job.n_intervals,
-                n_instructions=job.n_instructions,
-                n_clips=job.n_clips,
-                predicted_cycles=job.predicted_cycles,
-                oracle_cycles=job.oracle_cycles if self.with_oracle
-                else None,
-                func_seconds=job.func_seconds,
-                predict_seconds=pred.stats.predict_seconds
-                * (job.n_clips / total_clips),
-                oracle_seconds=job.oracle_seconds if self.with_oracle
-                else None) for job in jobs]
-            results.append(MulticoreSimResult(
-                name=mb.name, n_cores=mb.n_cores, cores=cores))
-        return results
-
-    def _run_multicore_sampled(
-            self, mbenches: Sequence[multicore.MulticoreBenchmark],
-            quantum: int) -> List[MulticoreSimResult]:
-        """Fusion path of ``run_multicore()``: each core's clips (all
-        checkpoints) collect in a per-core sink, then the per-core
-        stratified sample feeds the pooled predictor in core order.
-        One ``fuse_predictions`` per (benchmark, core) job; the job key
-        counts flattened jobs so every core draws independently but
-        reproducibly."""
-        scfg = self.config.sampling
-        self.frontend_stats = FrontendStats(self.obs, self.instance)
-        pred = BatchedPredictor(self.params, self.cfg, config=self.config,
-                                rt_cache=self._rt_cache,
-                                fault_injector=self._faults, obs=self.obs)
-        rt_stats = (self._rt_cache.stats if self._rt_cache is not None
-                    else RTCacheStats())
-        all_jobs: List[List[_Job]] = []
-        plans = []                 # (job, features, strata, sampled)
-        offset = 0
-        key = 0
-        for mb in mbenches:
-            cprogs = mb.compiled()
-            token_tables = [cp.token_table(self.vocab, self.l_token)
-                            for cp in cprogs]
-            static_ids = None
-            if self._rt_cache is not None:
-                static_ids = [
-                    self._rt_cache.ensure_rows(
-                        tt, keys=cp.token_row_keys(self.vocab,
-                                                   self.l_token))
-                    for cp, tt in zip(cprogs, token_tables)]
-            jobs = [_Job(bench=mb, name=f"{mb.name}#c{c}")
-                    for c in range(mb.n_cores)]
-            all_jobs.append(jobs)
-            sinks: List[list] = [[] for _ in range(mb.n_cores)]
-            states = mb.fresh_states()
-            d0 = pred.stats.dispatch_seconds
-            b0 = rt_stats.build_seconds
-            oracle_s = 0.0
-            with self.obs.span("engine.job", instance=self.instance,
-                               args={"bench": mb.name}) as jsp:
-                if self.warmup:
-                    with self.obs.span("engine.interpret",
-                                       instance=self.instance):
-                        multicore.run_multicore(cprogs, self.warmup,
-                                                states, quantum=quantum)
-                n_ckp = min(mb.ckp_num, self.max_checkpoints)
-                for _ in range(n_ckp):
-                    mtrace = self._multicore_interval(mb, cprogs, states,
-                                                      quantum)
-                    if len(mtrace) == 0:
-                        break
-                    peers = (mtrace.peer_snapshots
-                             or [None] * len(mtrace.cores))
-                    for c, trace in enumerate(mtrace.cores):
-                        if not len(trace):
-                            continue
-                        self._feed_trace(
-                            trace, token_tables[c],
-                            static_ids[c] if static_ids is not None
-                            else None,
-                            pred, jobs[c], core_id=c, sink=sinks[c],
-                            peer_snapshots=peers[c])
-                    if self.with_oracle:
-                        with self.obs.span("engine.oracle",
-                                           instance=self.instance) as osp:
-                            totals = timing.total_cycles_multicore(
-                                mtrace.cores, mtrace.schedule,
-                                self.timing_params)
-                        dt = osp.seconds
-                        oracle_s += dt
-                        for c, cyc in enumerate(totals):
-                            jobs[c].oracle_cycles += cyc
-                            jobs[c].oracle_seconds += dt / mb.n_cores
-                for c, job in enumerate(jobs):
-                    feats, strata, sampled = self._feed_sample(
-                        pred, sinks[c], job, key)
-                    key += 1
-                    job.offset = offset
-                    offset += int(sampled.shape[0])
-                    plans.append((job, feats, strata, sampled))
-            mb_seconds = (jsp.seconds - oracle_s
-                          - (pred.stats.dispatch_seconds - d0)
-                          - (rt_stats.build_seconds - b0))
-            mb_clips = max(sum(j.n_clips for j in jobs), 1)
-            for job in jobs:
-                job.func_seconds = mb_seconds * (job.n_clips / mb_clips)
-
-        preds = pred.drain()
-        if self._rt_cache is not None:
-            self._rt_cache.persist()          # no-op without a store_dir
-        self.last_stats = pred.stats
-        self.last_rt_stats = (rt_stats.freeze()
-                              if self._rt_cache is not None else None)
-        assert preds.shape[0] == offset == pred.stats.n_predicted, \
-            "clip accounting mismatch between sample and predictions"
-
-        total_sampled = max(offset, 1)
-        reports: Dict[int, Tuple[analytical.PredictionReport, int]] = {}
-        for k, (job, feats, strata, sampled) in enumerate(plans):
-            n_samp = int(sampled.shape[0])
-            mine = preds[job.offset:job.offset + n_samp]
-            rep = analytical.fuse_predictions(
-                feats, strata, sampled, mine,
-                bootstrap_resamples=scfg.bootstrap_resamples,
-                seed=scfg.seed, key=k)
-            reports[id(job)] = (rep, n_samp)
-
-        results = []
-        for mb, jobs in zip(mbenches, all_jobs):
-            cores = []
-            for job in jobs:
-                rep, n_samp = reports[id(job)]
-                cores.append(SimResult(
-                    name=job.name,
-                    n_intervals=job.n_intervals,
-                    n_instructions=job.n_instructions,
-                    n_clips=job.n_clips,
-                    predicted_cycles=rep.total_cycles,
-                    oracle_cycles=job.oracle_cycles if self.with_oracle
-                    else None,
-                    func_seconds=job.func_seconds,
-                    predict_seconds=pred.stats.predict_seconds
-                    * (n_samp / total_sampled),
-                    oracle_seconds=job.oracle_seconds if self.with_oracle
-                    else None,
-                    cycles_ci=rep.cycles_ci,
-                    clips_predicted=rep.clips_predicted,
-                    clips_extrapolated=rep.clips_extrapolated,
-                    clip_provenance=rep.clip_provenance))
-            results.append(MulticoreSimResult(
-                name=mb.name, n_cores=mb.n_cores, cores=cores))
-        return results
+        cores = iter(self._finish_pass(pred, rt_stats, all_jobs, segments))
+        return [MulticoreSimResult(
+                    name=mb.name, n_cores=mb.n_cores,
+                    cores=[next(cores) for _ in range(mb.n_cores)])
+                for mb in mbenches]

@@ -1,7 +1,7 @@
 """EngineConfig: the one construction surface for the inference engines.
 
-Every serving entry point — ``SimulationEngine`` / ``BatchedPredictor``
-(core), ``PredictorEngine`` (serving), ``capsim_simulate`` /
+Every entry point — ``SimulationEngine`` / ``BatchedPredictor`` (core),
+``SimulationService`` (serving), ``capsim_simulate`` /
 ``capsim_simulate_multicore`` (wrappers), and ``launch/serve.py`` — used
 to re-declare the same knob set as loose keyword arguments, so adding an
 axis (precision, RT cache, multicore N, and now the device mesh) meant

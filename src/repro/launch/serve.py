@@ -1,8 +1,9 @@
 """Serving launcher: ``python -m repro.launch.serve --arch capsim``.
 
-Runs the clip-parallel PredictorEngine over functional-sim requests from
-the synthetic suite (the CAPSim deployment), or a KV-cache decode loop for
-an LM-zoo arch (prefill + N decode steps on the smoke config).
+Runs ``SimulationEngine`` over the synthetic suite (the CAPSim
+deployment), or with ``--service`` the ``SimulationService`` front end
+over requests cut from it, or a KV-cache decode loop for an LM-zoo arch
+(prefill + N decode steps on the smoke config).
 
 The capsim path is a thin wrapper over ``SimulationEngine.from_config``:
 flags assemble one ``EngineConfig`` (``--engine-config`` takes a JSON

@@ -1,5 +1,4 @@
-from repro.serving.engine import (PredictorEngine, Request,  # noqa: F401
-                                  Result, validate_request)
+from repro.serving.engine import Request, validate_request  # noqa: F401
 from repro.serving.faults import (FaultInjected,  # noqa: F401
                                   FaultInjector)
 from repro.serving.service import (ServiceResult, ServiceSLA,  # noqa: F401

@@ -1,47 +1,21 @@
-"""Clip-parallel predictor serving engine (the CAPSim deployment path).
+"""The request a tenant sends the simulation service, and its check.
 
 A *request* is one benchmark interval: the functional trace's clips
-(tokenized) whose predicted runtimes must be summed.  The engine packs
-clips from many concurrent requests into fixed-shape device batches,
-runs the jit'd predictor, and scatters the per-clip times back to their
-requests — so throughput is set by total clip count, not by request
-boundaries.  This is exactly why CAPSim's speedup grows with checkpoint
-count (paper Fig 7): requests never serialize.
-
-The batch backend is ``repro.core.engine.BatchedPredictor``: the shared
-cached-jit predict step (no re-trace per engine instance), size-bucketed
-remainder padding (bounded compiled shapes), and async double-buffered
-dispatch.  On top of it sits the static-instruction RT cache
-(``repro.core.rt_cache``, on by default): request token rows are deduped
-against a content-addressed table that persists *across flushes*, so a
-steady request stream pays the 4-layer instruction encoder only for
-never-before-seen static rows and every clip runs block-encoder-only
-FLOPs.  ``precision="bf16"`` selects the low-precision inference mode
-(fp32 master params cast at dispatch; relative-error bounded).
-
-The engine is synchronous-by-batch (submit/flush) and holds ONE backend
-(``BatchedPredictor``) for its whole lifetime: the cached jit step, the
-RT table, and — under ``fused_serving`` — the per-table-version cross-K/V
-serving plan all survive across flushes, so a steady request stream pays
-plan precompute only when the table actually grows, never per flush.
-The production front-end that puts a queue, deadlines and graceful
-degradation on top is ``repro.serving.service.SimulationService``.
+(tokenized), each with its context row and mask, whose predicted
+runtimes the service sums.  ``validate_request`` checks a request's
+whole payload at the submission boundary.  The served path that packs
+many requests' clips into shared device batches is
+``repro.serving.service.SimulationService``.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Tuple
+from typing import Optional
 
 import numpy as np
 
-from repro.core import analytical
 from repro.core import context as ctx_mod
-from repro.core import predictor as pred_mod
-from repro.core import sampler as sampler_mod
-from repro.core.engine import BatchedPredictor
-from repro.core.engine_config import EngineConfig, reject_legacy_kwargs
-from repro.core.rt_cache import RTCache, RTCacheStats
-from repro.obs import Observability
+from repro.core.engine_config import EngineConfig
 
 
 @dataclasses.dataclass
@@ -54,31 +28,13 @@ class Request:
     clip_mask: np.ndarray             # (n, l_clip) float32
 
 
-@dataclasses.dataclass
-class Result:
-    request_id: int
-    total_cycles: float
-    n_clips: int
-    seconds: float
-    # --- PredictionReport fields (config.sampling flushes only) ---
-    cycles_ci: Optional[Tuple[float, float]] = None
-    clips_predicted: Optional[int] = None     # None -> every clip (full path)
-    clips_extrapolated: int = 0
-
-    def __post_init__(self):
-        if self.clips_predicted is None:
-            self.clips_predicted = self.n_clips
-
-
 def validate_request(req: Request, config: EngineConfig,
                      expect: Optional[tuple] = None) -> None:
     """Full submission-boundary payload check: ndims, dtypes, and
     internal shape consistency of every array (not just the context
     width).  ``expect=(l_clip, l_token)`` additionally pins the clip
-    shape — the ``SimulationService`` pins it to its config, and the
-    raw engine pins it to the flush's first request (the engine itself
-    is shape-polymorphic in ``l_clip`` across flushes, but one flush's
-    clips concatenate into shared device batches).  Raises
+    shape — the ``SimulationService`` pins it to its config, because
+    one flush's clips concatenate into shared device batches.  Raises
     ``ValueError`` naming the request and the offending field — a
     malformed tenant payload must never surface as a downstream
     concatenate/jit shape error (or worse, a silently wrong gather)."""
@@ -110,177 +66,3 @@ def validate_request(req: Request, config: EngineConfig,
     if not np.issubdtype(mask.dtype, np.floating):
         raise ValueError(f"{who}: clip_mask dtype {mask.dtype} is not a "
                          f"float mask dtype (expected float32)")
-
-
-class PredictorEngine:
-    """Construction is config-first: batching, precision, RT cache and
-    the device mesh all travel in one ``EngineConfig`` (a non-empty
-    ``mesh_shape`` shards every flush's device batches AND the RT-cache
-    encode passes over the data mesh, bitwise equal to unsharded).
-    ``config.sampling`` switches flushes to the analytical-ML fusion
-    path: only a stratified sample of each request's clips runs through
-    the predictor, the rest extrapolate from token-derived features, and
-    each ``Result`` carries a bootstrap CI.  The pre-PR-6 loose keyword
-    signature is retired: extra keywords raise ``TypeError`` pointing at
-    ``EngineConfig``."""
-
-    def __init__(self, params, cfg,
-                 config: Optional[EngineConfig] = None, **legacy):
-        reject_legacy_kwargs(legacy, "PredictorEngine")
-        config = config or EngineConfig()
-        self.config = config
-        self.obs = Observability.from_config(config.observability)
-        self.instance = self.obs.metrics.next_instance("pengine")
-        if config.precision == "int8":
-            from repro.core import quant
-            params = quant.quantize_dequant_params(params)
-        self.params = params
-        self.cfg = pred_mod.inference_config(cfg, config.precision)
-        self.batch_size = config.batch_size
-        self.use_context = config.use_context
-        self.max_in_flight = config.max_in_flight
-        # params are pinned for the engine's lifetime, so the RT table
-        # survives across flushes: only unseen static rows ever encode.
-        # The cache shares the engine's mesh: encode passes shard too.
-        # With rt_store_dir the table additionally survives across
-        # *process restarts* (content-keyed load-or-rebuild).
-        if config.rt_cache:
-            from repro.core.standardize import build_vocab
-            self._cache = RTCache(params, self.cfg, config.l_token,
-                                  n_shards=config.n_shards,
-                                  store_dir=config.rt_store_dir,
-                                  store_extra=build_vocab().signature(),
-                                  obs=self.obs)
-        else:
-            self._cache = None
-        self._faults = None
-        if config.faults:
-            from repro.serving.faults import FaultInjector
-            self._faults = FaultInjector.from_config(config)
-        self._pending: List[Request] = []
-        # ONE backend for the engine's lifetime (see module docstring):
-        # rebuilding per flush rebuilt the fused serving_plan every time
-        self._backend: Optional[BatchedPredictor] = None
-
-    @classmethod
-    def from_config(cls, params, cfg,
-                    config: Optional[EngineConfig] = None
-                    ) -> "PredictorEngine":
-        """Canonical constructor (mirrors ``SimulationEngine``)."""
-        return cls(params, cfg, config)
-
-    @property
-    def rt_stats(self) -> Optional[RTCacheStats]:
-        return self._cache.stats if self._cache is not None else None
-
-    def submit(self, req: Request) -> None:
-        """Queue one request, validating the full payload contract at
-        the submission boundary (with the producer on the stack), not as
-        a shape error inside a later concatenate or jit re-trace.  The
-        flush's first request pins its clip shape."""
-        expect = (self._pending[0].clip_tokens.shape[1:]
-                  if self._pending else None)
-        validate_request(req, self.config, expect)
-        self._pending.append(req)
-
-    def backend(self) -> BatchedPredictor:
-        """The engine-lifetime batch backend (built lazily on first
-        flush, then reused: cached jit step, RT table, and fused
-        serving plan all persist)."""
-        if self._backend is None:
-            self._backend = BatchedPredictor(self.params, self.cfg,
-                                             config=self.config,
-                                             rt_cache=self._cache,
-                                             fault_injector=self._faults,
-                                             obs=self.obs)
-        return self._backend
-
-    def flush(self) -> List[Result]:
-        """Run every pending clip through the predictor; one device batch
-        may span many requests."""
-        if not self._pending:
-            return []
-        reqs = self._pending
-        self._pending = []
-        if self.config.sampling is not None:
-            return self._flush_sampled(reqs)
-        with self.obs.span("serving.flush", instance=self.instance,
-                           args={"requests": len(reqs)}) as sp:
-            backend = self.backend()
-            # flushes are independent: each may carry a different (but
-            # internally consistent) context layout
-            backend.reset_context_width()
-            for r in reqs:
-                backend.add(r.clip_tokens, r.context_tokens, r.clip_mask)
-            times = backend.drain()           # exactly this flush's clips
-            if self._cache is not None:
-                self._cache.persist()         # no-op without a store_dir
-        n = times.shape[0]
-        seconds = sp.seconds
-
-        results = []
-        off = 0
-        for r in reqs:
-            k = r.clip_tokens.shape[0]
-            results.append(Result(
-                request_id=r.request_id,
-                total_cycles=float(times[off:off + k].sum()),
-                n_clips=k,
-                seconds=seconds * (k / max(n, 1))))
-            off += k
-        return results
-
-    def _flush_sampled(self, reqs: List[Request]) -> List[Result]:
-        """Fusion path of ``flush()``: per request, stratify on
-        token-derived features (``analytical.token_clip_features`` —
-        serving never sees the columnar trace), predict only the
-        stratified sample, extrapolate the rest, and attach the
-        bootstrap CI.  Every request still resolves to exactly one
-        typed ``Result``; the draw is keyed by ``request_id`` so a
-        retried request samples identically."""
-        scfg = self.config.sampling
-        plans = []
-        with self.obs.span("serving.flush", instance=self.instance,
-                           args={"requests": len(reqs),
-                                 "sampled": True}) as sp:
-            backend = self.backend()
-            backend.reset_context_width()
-            for r in reqs:
-                feats = analytical.token_clip_features(r.clip_tokens,
-                                                       r.clip_mask)
-                # token features have no analytical-cycles column; clip
-                # occupancy (column 0) is the work-amount proxy
-                strata = analytical.stratify(feats, scfg.strata,
-                                             key_column=0)
-                sampled, _ = sampler_mod.stratified_sample(
-                    strata, scfg.fraction, scfg.min_clips_per_stratum,
-                    scfg.seed, key=r.request_id)
-                if sampled.shape[0]:
-                    backend.add(r.clip_tokens[sampled],
-                                r.context_tokens[sampled],
-                                r.clip_mask[sampled])
-                plans.append((feats, strata, sampled))
-            preds = backend.drain()           # exactly the sampled clips
-            if self._cache is not None:
-                self._cache.persist()         # no-op without a store_dir
-        n = preds.shape[0]
-        seconds = sp.seconds
-
-        results = []
-        off = 0
-        for r, (feats, strata, sampled) in zip(reqs, plans):
-            k = int(sampled.shape[0])
-            rep = analytical.fuse_predictions(
-                feats, strata, sampled, preds[off:off + k],
-                bootstrap_resamples=scfg.bootstrap_resamples,
-                seed=scfg.seed, key=r.request_id)
-            results.append(Result(
-                request_id=r.request_id,
-                total_cycles=rep.total_cycles,
-                n_clips=int(r.clip_tokens.shape[0]),
-                seconds=seconds * (k / max(n, 1)),
-                cycles_ci=rep.cycles_ci,
-                clips_predicted=rep.clips_predicted,
-                clips_extrapolated=rep.clips_extrapolated))
-            off += k
-        return results

@@ -1,11 +1,10 @@
 """Fault-tolerant continuous-batching simulation service.
 
-``PredictorEngine`` is a synchronous flush front-end: callers block, a
-stuck flush hangs everyone, and a misbehaving fast path (fused / int8 /
-RT store) returns whatever it returns.  ``SimulationService`` is the
-production front: the contract is that **every admitted request ends in
-a typed result** — a success, a degraded-tier success, or a clean,
-immediate rejection — never a hang, and never an ungated wrong answer.
+``SimulationService`` is the one served path: requests
+(``repro.serving.engine.Request``) share device batches, and the
+contract is that **every admitted request ends in a typed result** — a
+success, a degraded-tier success, or a clean, immediate rejection —
+never a hang, and never an ungated wrong answer.
 
 Structure:
 
@@ -899,8 +898,8 @@ class SimulationService:
         times vector — so the NaN guard and per-request scatter in
         ``_serve_batch`` (and hence the typed-result contract) are
         untouched.  The bootstrap is skipped here: ``ServiceResult``
-        carries totals, not intervals — use ``PredictorEngine`` with
-        sampling for CIs."""
+        carries totals, not intervals (``SimulationEngine.run`` under
+        sampling gives each program a bootstrap CI)."""
         scfg = self.config.sampling
         plans = []
         for qr in batch:

@@ -1,14 +1,13 @@
 """EngineConfig: the unified construction surface (PR 6 satellite).
 
 Covers: validation + JSON round trip (including the nested
-``SamplingConfig``), the retired PR-6 kwarg shims on all four entry
-points (legacy keywords must raise ``TypeError`` pointing at
+``SamplingConfig``), the retired keyword shims on every entry
+point (legacy keywords must raise ``TypeError`` pointing at
 ``EngineConfig``), and ``from_config`` equivalence.
 """
 import warnings
 
 import jax
-import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -19,7 +18,6 @@ from repro.core.engine_config import (EngineConfig, SamplingConfig,
                                       reject_legacy_kwargs)
 from repro.core.simulate import capsim_simulate, capsim_simulate_multicore
 from repro.isa import multicore, progen
-from repro.serving.engine import PredictorEngine, Request
 
 SMALL_CFG = get_config("capsim").replace(d_model=32, head_dim=8, d_ff=64,
                                          dtype="float32")
@@ -173,24 +171,6 @@ def test_engine_construction_does_not_warn(params, vocab):
 def test_batched_predictor_legacy_kwargs_raise(params, vocab):
     with pytest.raises(TypeError, match="EngineConfig"):
         BatchedPredictor(params, SMALL_CFG, batch_size=16)
-
-
-def test_predictor_engine_legacy_kwargs_raise(params, vocab):
-    with pytest.raises(TypeError, match="EngineConfig"):
-        PredictorEngine(params, SMALL_CFG, batch_size=8)
-    # the config path still serves
-    rng = np.random.RandomState(1)
-    tok = rng.randint(0, vocab.size, (4, 128, SMALL_CFG.clip_tokens)
-                      ).astype(np.int32)
-    ctx = rng.randint(0, vocab.size, (4, SMALL_CFG.context_tokens)
-                      ).astype(np.int32)
-    req = Request(0, tok, ctx, np.ones((4, 128), np.float32))
-    eng = PredictorEngine.from_config(params, SMALL_CFG,
-                                      EngineConfig(batch_size=8))
-    eng.submit(req)
-    res = eng.flush()[0]
-    assert res.n_clips == 4 and res.clips_predicted == 4
-    assert res.clips_extrapolated == 0 and res.cycles_ci is None
 
 
 # ------------------------------ entry points ------------------------------ #

@@ -24,7 +24,7 @@ from repro.core import context as ctx_mod
 from repro.core import predictor
 from repro.core import standardize as std_mod
 from repro.core.engine import SimulationEngine
-from repro.core.engine_config import EngineConfig
+from repro.core.engine_config import EngineConfig, SamplingConfig
 from repro.core.standardize import CORE, build_vocab
 from repro.isa import funcsim, multicore, progen, timing
 from repro.isa.compiled import IREG_SLOT, compile_program
@@ -317,6 +317,27 @@ def test_engine_clip_conservation(mc_engine_results):
         assert r.oracle_cycles == sum(cr.oracle_cycles for cr in r.cores)
         for cr in r.cores:
             assert cr.oracle_cycles > 0
+
+
+def test_engine_sampling_fraction_one_gives_unsampled_core_totals(
+        params, mc_engine_results):
+    """Core-tagged ``run_multicore`` under ``fraction=1.0`` feeds every
+    core's clips as one sample after the benchmark's checkpoints: the
+    per-core totals are the unsampled ones (one sum over the core's
+    rows instead of one per checkpoint, so to float32 rounding)."""
+    mbenches, results, engine = mc_engine_results
+    sampled = SimulationEngine(params, SMALL_CFG, VOCAB, SIM_EC.replace(
+        sampling=SamplingConfig(fraction=1.0)))
+    got = sampled.run_multicore(mbenches)
+    assert sampled.last_stats.n_predicted == engine.last_stats.n_predicted
+    for r, g in zip(results, got):
+        assert [c.name for c in g.cores] == [c.name for c in r.cores]
+        for rc, gc in zip(r.cores, g.cores):
+            assert gc.n_clips == gc.clips_predicted == rc.n_clips
+            assert gc.clips_extrapolated == 0
+            assert gc.oracle_cycles == rc.oracle_cycles
+            assert gc.predicted_cycles == pytest.approx(
+                rc.predicted_cycles, rel=1e-6)
 
 
 def test_rt_cache_shared_across_cores(params):

@@ -6,6 +6,7 @@ import pytest
 from repro.configs import get_config
 from repro.core import context as ctx_mod
 from repro.core import predictor
+from repro.core.engine import BatchedPredictor
 from repro.core.engine_config import EngineConfig
 from repro.core.intervals import basic_block_leaders, pick_intervals
 from repro.core.simulate import capsim_simulate
@@ -14,7 +15,9 @@ from repro.data.dataset import (BuildConfig, batches, build_dataset,
                                 shard_range, split_dataset)
 from repro.isa import progen
 from repro.isa.isa import Instruction
-from repro.serving.engine import PredictorEngine, Request
+from repro.serving.engine import Request
+from repro.serving.service import (ServiceSLA, SimulationService,
+                                   build_ladder)
 
 VOCAB = build_vocab()
 TINY_BCFG = BuildConfig(interval_size=2_000, warmup=200, max_checkpoints=2,
@@ -89,47 +92,50 @@ def test_basic_block_leaders():
 
 
 def test_serving_engine_multi_request(tiny_ds):
+    """Two requests in one service window share device batches and
+    scatter back to their own totals; the rt rung's RT table persists
+    across flushes; and the monolithic path agrees bitwise."""
     params = predictor.init_params(SMALL_CFG, jax.random.PRNGKey(0))
-    engine = PredictorEngine(params, SMALL_CFG,
-                             EngineConfig(batch_size=8))
+    config = EngineConfig(batch_size=8, l_clip=TINY_BCFG.l_clip)
+    rt = [name for name, _ in build_ladder(config)].index("rt")
+    svc = SimulationService(params, SMALL_CFG, config, start_tier=rt,
+                            sla=ServiceSLA(check_every=0,
+                                           promote_after=1_000,
+                                           watchdog_s=120.0))
+
+    def req(i, lo, hi):
+        return Request(i, tiny_ds.clip_tokens[lo:hi],
+                       tiny_ds.context_tokens[lo:hi],
+                       tiny_ds.clip_mask[lo:hi])
+
     n1, n2 = 5, 9
-    engine.submit(Request(1, tiny_ds.clip_tokens[:n1],
-                          tiny_ds.context_tokens[:n1],
-                          tiny_ds.clip_mask[:n1]))
-    engine.submit(Request(2, tiny_ds.clip_tokens[n1:n1 + n2],
-                          tiny_ds.context_tokens[n1:n1 + n2],
-                          tiny_ds.clip_mask[n1:n1 + n2]))
-    results = engine.flush()
-    assert [r.request_id for r in results] == [1, 2]
-    assert results[0].n_clips == n1 and results[1].n_clips == n2
-    assert all(r.total_cycles > 0 for r in results)
-    # batching across requests == predicting each clip alone
-    lone = PredictorEngine(params, SMALL_CFG,
-                           EngineConfig(batch_size=8))
-    lone.submit(Request(3, tiny_ds.clip_tokens[:n1],
-                        tiny_ds.context_tokens[:n1],
-                        tiny_ds.clip_mask[:n1]))
-    alone = lone.flush()[0]
-    np.testing.assert_allclose(alone.total_cycles, results[0].total_cycles,
-                               rtol=1e-5)
-    # the RT cache persists across flushes: replaying request 1 encodes
-    # zero new static rows and reproduces the pooled result bitwise
-    rt = engine.rt_stats
-    assert rt is not None and rt.n_rows_encoded > 0
-    encoded_before = rt.n_rows_encoded
-    engine.submit(Request(4, tiny_ds.clip_tokens[:n1],
-                          tiny_ds.context_tokens[:n1],
-                          tiny_ds.clip_mask[:n1]))
-    replay = engine.flush()[0]
-    assert rt.n_rows_encoded == encoded_before
+    # admit both before the worker runs, so one window serves them
+    svc._running = True
+    tickets = [svc.submit(req(1, 0, n1)), svc.submit(req(2, n1, n1 + n2))]
+    svc._running = False
+    with svc:
+        results = [t.result(timeout=300) for t in tickets]
+        assert svc.stats()["tiers"]["rt"]["flushes"] == 1
+        assert [r.request_id for r in results] == [1, 2]
+        assert results[0].n_clips == n1 and results[1].n_clips == n2
+        assert all(r.status == "degraded" and r.tier == "rt"
+                   for r in results)
+        assert all(r.total_cycles > 0 for r in results)
+        # the RT cache persists across flushes: replaying request 1
+        # alone encodes zero new static rows and reproduces the pooled
+        # result bitwise (batching across requests == each alone)
+        stats = svc._tiers[rt].cache.stats
+        encoded_before = stats.n_rows_encoded
+        assert encoded_before > 0
+        replay = svc.submit(req(4, 0, n1)).result(timeout=300)
+        assert stats.n_rows_encoded == encoded_before
     assert replay.total_cycles == results[0].total_cycles
     # and the monolithic reference path agrees
-    mono = PredictorEngine(params, SMALL_CFG,
-                           EngineConfig(batch_size=8, rt_cache=False))
-    mono.submit(Request(5, tiny_ds.clip_tokens[:n1],
-                        tiny_ds.context_tokens[:n1],
-                        tiny_ds.clip_mask[:n1]))
-    assert mono.flush()[0].total_cycles == replay.total_cycles
+    mono = BatchedPredictor(params, SMALL_CFG,
+                            config=config.replace(rt_cache=False))
+    r = req(5, 0, n1)
+    mono.add(r.clip_tokens, r.context_tokens, r.clip_mask)
+    assert float(mono.drain().sum()) == replay.total_cycles
 
 
 def test_capsim_simulate_end_to_end():

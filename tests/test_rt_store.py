@@ -288,16 +288,25 @@ def test_concurrent_persist_last_writer_wins(params, table, tmp_path):
 
 
 def test_two_engines_share_store_dir(params, tmp_path):
-    """Two serving engines flush (and persist) concurrently into one
-    rt_store_dir; a third engine then loads whatever generation won and
+    """Two simulation services flush (and persist) concurrently into one
+    rt_store_dir; a third service then loads whatever generation won and
     still serves bitwise-correct results."""
     import threading
 
-    from repro.serving.engine import PredictorEngine, Request
+    from repro.serving.engine import Request
+    from repro.serving.service import (ServiceSLA, SimulationService,
+                                       build_ladder)
 
     ec = EngineConfig(l_clip=32, l_token=16, batch_size=16,
                       rt_store_dir=str(tmp_path))
-    rng = np.random.RandomState(0)
+    # serve on the f32 RT rung for the whole test: no promotion, no
+    # spot check
+    rt = [name for name, _ in build_ladder(ec)].index("rt")
+    sla = ServiceSLA(watchdog_s=120.0, check_every=0, promote_after=1_000)
+
+    def service():
+        return SimulationService(params, SMALL_CFG, ec, sla=sla,
+                                 start_tier=rt)
 
     def mk_req(i, seed):
         r = np.random.RandomState(seed)
@@ -310,10 +319,12 @@ def test_two_engines_share_store_dir(params, tmp_path):
 
     def serve(name, seed):
         try:
-            eng = PredictorEngine(params, SMALL_CFG, ec)
-            for rnd in range(3):            # each flush persists
-                eng.submit(mk_req(rnd, seed + rnd))
-                results[(name, rnd)] = eng.flush()[0].total_cycles
+            with service() as svc:
+                for rnd in range(3):        # each flush persists
+                    res = svc.submit(mk_req(rnd, seed + rnd)).result(
+                        timeout=300)
+                    assert res.tier == "rt", res
+                    results[(name, rnd)] = res.total_cycles
         except Exception as exc:            # pragma: no cover
             errs.append(exc)
 
@@ -322,14 +333,15 @@ def test_two_engines_share_store_dir(params, tmp_path):
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=600)
+        assert not t.is_alive()
     assert not errs and len(results) == 6
 
-    eng3 = PredictorEngine(params, SMALL_CFG, ec)
-    assert eng3.rt_stats is not None
-    eng3.submit(mk_req(0, 100))
-    eng3.submit(mk_req(0, 200))
-    got = eng3.flush()
-    assert eng3.rt_stats.n_rows_loaded > 0      # adopted a winner
+    with service() as svc3:
+        got = [svc3.submit(mk_req(0, seed)).result(timeout=300)
+               for seed in (100, 200)]
+        stats = svc3._tiers[rt].cache.stats
+    assert stats.n_rows_loaded > 0              # adopted a winner
+    assert all(r.tier == "rt" for r in got)
     assert got[0].total_cycles == results[("e1", 0)]
     assert got[1].total_cycles == results[("e2", 0)]

@@ -12,10 +12,11 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core import predictor
-from repro.core.engine_config import EngineConfig
+from repro.core import predictor, quant
+from repro.core.engine import BatchedPredictor
+from repro.core.engine_config import EngineConfig, SamplingConfig
 from repro.core.standardize import build_vocab
-from repro.serving.engine import PredictorEngine, Request
+from repro.serving.engine import Request
 from repro.serving.faults import FaultInjector
 from repro.serving.service import (STATUSES, DegradationController,
                                    ServiceSLA, SimulationService,
@@ -45,6 +46,17 @@ def _sla(**kw):
     kw.setdefault("watchdog_s", 120.0)       # compile-safe on slow CI
     kw.setdefault("promote_after", 1)
     return ServiceSLA(**kw)
+
+
+def _plain_totals(params, config, reqs):
+    """Each request's total from a plain monolithic ``BatchedPredictor``
+    (no RT cache, no service), one drain per request."""
+    bp = BatchedPredictor(params, SMALL_CFG, config=config)
+    totals = []
+    for r in reqs:
+        bp.add(r.clip_tokens, r.context_tokens, r.clip_mask)
+        totals.append(float(bp.drain().sum()))
+    return totals
 
 
 # --------------------------------------------------------------------------- #
@@ -87,47 +99,55 @@ def test_degradation_controller_backoff():
 
 
 # --------------------------------------------------------------------------- #
-# Request validation + persistent engine backend
+# Request validation + persistent tier backend
 # --------------------------------------------------------------------------- #
 
 def test_submit_validates_shapes_and_dtypes(params):
-    eng = PredictorEngine(params, SMALL_CFG, BASE)
+    svc = SimulationService(params, SMALL_CFG, BASE, sla=_sla())
     good = _req(0)
-    eng.submit(good)
+    # a valid request passes the check (and, not started, is shed typed)
+    assert svc.submit(good).result().status == "overloaded"
     bad_rank = Request(1, good.clip_tokens[:, 0], good.context_tokens,
                        good.clip_mask)
     with pytest.raises(ValueError, match="clip_tokens"):
-        eng.submit(bad_rank)
+        svc.submit(bad_rank)
     with pytest.raises(ValueError, match="l_clip"):
-        eng.submit(Request(2, good.clip_tokens[:, :7], good.context_tokens,
+        svc.submit(Request(2, good.clip_tokens[:, :7], good.context_tokens,
                            good.clip_mask[:, :7]))
     with pytest.raises(ValueError, match="dtype"):
-        eng.submit(Request(3, good.clip_tokens.astype(np.float32),
+        svc.submit(Request(3, good.clip_tokens.astype(np.float32),
                            good.context_tokens, good.clip_mask))
     with pytest.raises(ValueError, match="context_tokens"):
-        eng.submit(Request(4, good.clip_tokens,
+        svc.submit(Request(4, good.clip_tokens,
                            good.context_tokens[:2], good.clip_mask))
     with pytest.raises(ValueError, match="clip_mask"):
-        eng.submit(Request(5, good.clip_tokens, good.context_tokens,
+        svc.submit(Request(5, good.clip_tokens, good.context_tokens,
                            good.clip_mask.astype(np.int32)))
     with pytest.raises(ValueError, match="context"):
-        eng.submit(Request(6, good.clip_tokens,
+        svc.submit(Request(6, good.clip_tokens,
                            good.context_tokens[:, :13], good.clip_mask))
 
 
 def test_engine_backend_persists_across_flushes(params):
-    eng = PredictorEngine(params, SMALL_CFG, BASE)
-    eng.submit(_req(0))
-    r1 = eng.flush()[0]
-    backend = eng.backend()
-    eng.submit(_req(1, n=3))
-    eng.submit(_req(0))
-    r2 = eng.flush()
-    assert eng.backend() is backend           # ONE backend, reused
-    assert [r.n_clips for r in r2] == [3, 4]
-    assert r2[1].total_cycles == r1.total_cycles   # replay is bitwise
-    # the RT table persisted across flushes: replay encoded nothing new
-    assert eng.rt_stats.n_rows_encoded > 0
+    """A service tier holds one backend, and with it the RT table and
+    the fused serving plan, across flushes: a replayed request encodes
+    no row and rebuilds no plan."""
+    with SimulationService(params, SMALL_CFG, BASE,
+                           sla=_sla(check_every=0)) as svc:
+        tier = svc._tiers[0]
+        r1 = svc.submit(_req(0)).result(timeout=300)
+        backend = tier.backend()
+        plan = backend._plan
+        rows = tier.cache.stats.n_rows_encoded
+        replay = svc.submit(_req(0)).result(timeout=300)
+        assert tier.backend() is backend      # ONE backend, reused
+        assert backend._plan is plan          # the table did not grow
+        assert tier.cache.stats.n_rows_encoded == rows > 0
+        r2 = svc.submit(_req(1, n=3)).result(timeout=300)
+        assert tier.backend() is backend
+    assert r1.status == replay.status == r2.status == "ok"
+    assert r2.n_clips == 3
+    assert replay.total_cycles == r1.total_cycles   # replay is bitwise
 
 
 # --------------------------------------------------------------------------- #
@@ -142,12 +162,50 @@ def test_service_healthy_top_tier(params):
     assert all(r.tier == "fused_int8" for r in results)
     assert all(r.total_cycles and np.isfinite(r.total_cycles)
                for r in results)
-    # against the plain engine at the same rung: identical numbers
-    eng = PredictorEngine(params, SMALL_CFG, BASE.replace(
-        fused_serving=True, precision="int8"))
-    eng.submit(_req(0))
-    assert eng.flush()[0].total_cycles == pytest.approx(
-        results[0].total_cycles, rel=1e-6)
+    # against a plain predictor on the same int8 weights: identical
+    want = _plain_totals(quant.quantize_dequant_params(params),
+                         BASE.replace(precision="int8"), [_req(0)])
+    assert want[0] == pytest.approx(results[0].total_cycles, rel=1e-6)
+
+
+def _served(params, config, reqs):
+    """Serve ``reqs`` one flush each on the top rung (no spot checks);
+    returns the results and the clips that reached the rung's
+    predictor."""
+    with SimulationService(params, SMALL_CFG, config,
+                           sla=_sla(check_every=0)) as svc:
+        results = [svc.submit(r).result(timeout=300) for r in reqs]
+        fed = svc._tiers[0].backend().stats.n_predicted
+    return results, fed
+
+
+def test_service_sampling_fraction_one_gives_unsampled_totals(params):
+    """``fraction=1.0`` samples every clip: each request's total is the
+    unsampled service's total."""
+    reqs = [_req(i, n=16) for i in range(3)]
+    full, fed_full = _served(params, BASE, reqs)
+    got, fed = _served(params, BASE.replace(
+        sampling=SamplingConfig(fraction=1.0)), reqs)
+    assert fed == fed_full == sum(r.clip_tokens.shape[0] for r in reqs)
+    for f, g in zip(full, got):
+        assert f.status == g.status == "ok"
+        assert g.n_clips == f.n_clips
+        assert g.total_cycles == pytest.approx(f.total_cycles, rel=1e-6)
+
+
+def test_service_sampling_feeds_fewer_clips(params):
+    """At ``fraction=0.5`` every request still resolves ``ok`` with a
+    finite total for all its clips, and fewer clips reach the
+    predictor than were submitted."""
+    reqs = [_req(i, n=32) for i in range(3)]
+    got, fed = _served(params, BASE.replace(
+        sampling=SamplingConfig(fraction=0.5, strata=2,
+                                min_clips_per_stratum=1)), reqs)
+    assert all(r.status == "ok" and r.tier == "fused_int8" for r in got)
+    assert [r.n_clips for r in got] == [32] * 3
+    assert all(np.isfinite(r.total_cycles) and r.total_cycles > 0
+               for r in got)
+    assert 0 < fed < sum(r.clip_tokens.shape[0] for r in reqs)
 
 
 def test_service_sheds_when_queue_full(params):
@@ -245,13 +303,13 @@ def test_service_degraded_results_stay_gated(params):
                            fault_injector=inj) as svc:
         results = [svc.submit(_req(i)).result(timeout=600)
                    for i in range(6)]
-    ref = PredictorEngine(params, SMALL_CFG, BASE.replace(rt_cache=False))
+    ref = _plain_totals(params, BASE.replace(rt_cache=False),
+                        [_req(i) for i in range(6)])
     for i, r in enumerate(results):
         assert r.status in ("ok", "degraded", "failed")
         if not r.ok:
             continue
-        ref.submit(_req(i))
-        want = ref.flush()[0].total_cycles
+        want = ref[i]
         tol = 0.05 if r.tier == "fused_int8" else 1e-3
         assert abs(r.total_cycles - want) / abs(want) <= tol
 
